@@ -7,7 +7,7 @@ version uses the hardware efficiently — a single noise pattern already costs
 time (near-zero absorption in every mode).
 
 ``--pallas``: additionally run the study on the REAL tiled Pallas matmul
-kernel (interpret mode off-TPU) through the campaign spine, and report the
+kernel (in the Pallas interpreter) through the campaign spine, and report the
 compile-once vs trace-per-k sweep cost (executables built + wall-clock).
 """
 from __future__ import annotations

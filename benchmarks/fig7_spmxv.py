@@ -8,7 +8,7 @@ absorption first DROPS (bandwidth regime tightening) then RISES again
 invisible to plain performance numbers.
 
 ``--pallas``: additionally run the q-sweep on the REAL ELL SPMV Pallas
-kernel (interpret mode off-TPU) through the campaign spine, and report the
+kernel (in the Pallas interpreter) through the campaign spine, and report the
 compile-once vs trace-per-k sweep cost (executables built + wall-clock).
 """
 from __future__ import annotations

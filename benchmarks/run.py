@@ -71,7 +71,7 @@ def main() -> None:
                     help="measure every point afresh (no persistence)")
     ap.add_argument("--pallas", action="store_true",
                     help="also run fig4/fig7 on the real Pallas kernels "
-                         "(interpret mode off-TPU) and report the "
+                         "(in the Pallas interpreter) and report the "
                          "compile-once vs trace-per-k sweep cost")
     ap.add_argument("--emit-fleet-plan", default=None, metavar="PATH",
                     help="write a repro.fleet SweepPlan covering the "
@@ -82,6 +82,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.quick and args.full:
         ap.error("--quick and --full are mutually exclusive")
+    from repro.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.emit_fleet_plan:
         build_fleet_plan(
             not args.full, out=args.emit_fleet_plan,

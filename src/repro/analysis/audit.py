@@ -264,6 +264,16 @@ def audit_texts(clean_text: str, lo_text: str, hi_text: str, *,
                        detail=" ".join(pieces[:12]))
 
 
+def _check_censusable(target) -> None:
+    """A kernel compiled by Mosaic is one opaque ``tpu_custom_call`` in the
+    HLO: no census can see its noise, so the pair is unauditable (its
+    payload check verifies the noise arithmetically after the sweep)."""
+    if (target.audit_hint or {}).get("opaque"):
+        raise AuditError(f"{target.name}: the kernel body is compiled by "
+                         "Mosaic into one tpu_custom_call, opaque to the HLO "
+                         "census; the payload check verifies its noise")
+
+
 def compile_text(target, mode: str, k: int) -> str:
     """Compile ONE static build of a pair and return its optimized HLO text.
     No measurement happens: the executable is lowered and compiled, never
@@ -294,6 +304,7 @@ def audit_pair(target, mode: str, *, k_lo: int = K_LO, k_hi: int = K_HI,
     ``clean_text`` is shared), zero measurements."""
     from repro.core.controller import _default_target
 
+    _check_censusable(target)
     clean, lo, hi = compile_texts(target, mode, k_lo=k_lo, k_hi=k_hi,
                                   clean_text=clean_text)
     tgt = target.payload_target.get(mode, _default_target(mode))
@@ -319,6 +330,7 @@ def audit_plan(plan, *, skip=frozenset(), on_error=None) -> list[AuditReport]:
                 if (tgt.name, mode) in skip:
                     continue
                 try:
+                    _check_censusable(tgt)
                     if clean is None:
                         clean = compile_text(tgt, "", 0)
                     reports.append(audit_pair(tgt, mode, clean_text=clean))

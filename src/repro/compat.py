@@ -1,20 +1,9 @@
-"""JAX version-compatibility layer.
+"""Thin helpers over the JAX mesh and Pallas APIs the repo uses.
 
-The repo targets the modern mesh API (``jax.sharding.get_abstract_mesh``,
-``jax.sharding.AxisType``, ``jax.set_mesh``, ``jax.shard_map``) but must run
-on JAX 0.4.x where none of those exist. Every version-dependent call goes
-through the stable helpers below — no module under ``src/repro/`` may touch
-``jax.sharding.get_abstract_mesh`` / ``jax.sharding.AxisType`` directly.
-
-Policy: feature-detect once at import (getattr, never version string
-comparison), prefer the modern API when present, and fall back to the oldest
-equivalent that preserves semantics:
-
-  get_abstract_mesh  -> thread-local physical mesh (``with mesh:`` context)
-  AxisType.Auto      -> omitted (0.4.x meshes are implicitly "auto")
-  jax.set_mesh       -> jax.sharding.use_mesh -> ``with mesh:``
-  jax.shard_map      -> jax.experimental.shard_map (check_vma -> check_rep)
-  AbstractMesh(a, b) -> AbstractMesh(tuple(zip(names, sizes)))
+Every module under ``src/repro/`` reaches these surfaces through the helpers
+below (``tests/test_compat.py`` enforces it), so a future API move is one
+edit here. The repo runs on one installed JAX; the helpers carry no
+fallbacks for older releases.
 """
 from __future__ import annotations
 
@@ -23,132 +12,76 @@ from typing import Any, Optional
 
 import jax
 
-# ``AxisType`` is None on JAX versions that predate explicit/auto axis types.
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-_get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-_set_mesh = getattr(jax, "set_mesh", None) or getattr(jax.sharding, "use_mesh",
-                                                      None)
-_shard_map = getattr(jax, "shard_map", None)
+AxisType = jax.sharding.AxisType
 
 
 def axis_types_auto(n_axes: int) -> dict:
-    """``axis_types=(AxisType.Auto,) * n`` as a splat-able kwargs dict.
-
-    Empty on JAX versions without axis types, where every mesh axis already
-    behaves as Auto.
-    """
-    if AxisType is None:
-        return {}
+    """``axis_types=(AxisType.Auto,) * n`` as a splat-able kwargs dict."""
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types whenever the API supports them."""
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             **axis_types_auto(len(axis_names)))
-    except TypeError:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         **axis_types_auto(len(axis_names)))
 
 
 def abstract_mesh(axis_shapes, axis_names) -> "jax.sharding.AbstractMesh":
-    """Version-proof ``AbstractMesh`` constructor (sizes + names)."""
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(tuple(axis_shapes), tuple(axis_names),
-                  **axis_types_auto(len(axis_names)))
-    except (TypeError, ValueError):
-        # 0.4.x signature: AbstractMesh(((name, size), ...))
-        return AM(tuple(zip(axis_names, axis_shapes)))
+    """An ``AbstractMesh`` from sizes and names, with Auto axis types."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names),
+                                     **axis_types_auto(len(axis_names)))
 
 
 def get_abstract_mesh() -> Optional[Any]:
     """The mesh of the enclosing ``set_mesh`` context, or None.
 
-    Unlike the raw modern API (which returns an *empty* AbstractMesh when no
-    mesh is set), this normalizes to None whenever there is no usable mesh, so
+    Unlike the raw API (which returns an *empty* AbstractMesh when no mesh
+    is set), this normalizes to None whenever there is no usable mesh, so
     callers only ever branch on ``mesh is None``.
     """
-    if _get_abstract_mesh is not None:
-        m = _get_abstract_mesh()
-    else:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-    if m is None or getattr(m, "empty", False) or not m.axis_names:
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty or not m.axis_names:
         return None
     return m
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Enter ``mesh`` as the ambient mesh (modern: abstract mesh context;
-    0.4.x: the thread-local physical mesh that pjit and collectives read)."""
-    if _set_mesh is not None:
-        with _set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Enter ``mesh`` as the ambient mesh."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any JAX."""
-    if _shard_map is not None:
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-        except TypeError:
-            pass  # older keyword spelling below
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-    except ImportError:
-        sm = _shard_map
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def cost_analysis(compiled) -> Optional[dict]:
-    """``compiled.cost_analysis()`` normalized to a single dict (0.4.x wraps
-    the per-program properties in a one-element list)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else None
-    return cost
+    """``compiled.cost_analysis()`` (a dict, or None where the backend gives
+    none)."""
+    return compiled.cost_analysis()
 
 
 def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` (newer JAX) or the classic psum-of-ones."""
-    f = getattr(jax.lax, "axis_size", None)
-    if f is not None:
-        return f(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.lax.axis_size`` inside a mapped context."""
+    return jax.lax.axis_size(axis_name)
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:
-    """{axis name: size} for Mesh and AbstractMesh across versions."""
-    if hasattr(mesh, "axis_sizes"):
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    return dict(mesh.shape.items())
+    """{axis name: size} for Mesh and AbstractMesh."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid,
                               in_specs, out_specs, scratch_shapes=()):
     """A Pallas grid spec whose first ``num_scalar_prefetch`` operands are
     scalar-prefetch refs (SMEM-resident before the kernel body runs) — the
-    delivery channel for the runtime-k noise quantity.
-
-    Feature-detects the classic ``pltpu.PrefetchScalarGridSpec``; newer JAX
-    folds scalar prefetch into ``pl.GridSpec(num_scalar_prefetch=...)``.
-    """
-    from jax.experimental import pallas as pl
+    delivery channel for the runtime-k noise quantity."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "PrefetchScalarGridSpec", None)
-    if cls is not None:
-        return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-                   in_specs=in_specs, out_specs=out_specs,
-                   scratch_shapes=list(scratch_shapes))
-    return pl.GridSpec(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-                       in_specs=in_specs, out_specs=out_specs,
-                       scratch_shapes=list(scratch_shapes))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=list(scratch_shapes))

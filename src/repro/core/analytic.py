@@ -1,6 +1,6 @@
 """Analytic saturation model — absorption prediction for the TPU target.
 
-This container has no TPU, but the dry-run compile gives per-step roofline
+Without running the step, the dry-run compile gives per-step roofline
 terms T_r (seconds each resource is busy: compute / memory / ici / serial
 latency). The paper's Fig. 2 behaviour falls out of a two-parameter model:
 

@@ -180,11 +180,12 @@ def fit_thresholds(samples: Sequence[dict], *, default_low: float = LOW,
 
 
 def hw_name() -> str:
-    """The hardware-config key a ``calib`` record is stored under (the jax
-    backend platform: cpu/gpu/tpu)."""
+    """The hardware-config key a ``calib`` record is stored under: the
+    device kind JAX reports (e.g. "TPU v5 lite", "cpu"), so thresholds
+    fitted on one chip generation never classify runs on another."""
     import jax
 
-    return jax.default_backend()
+    return jax.devices()[0].device_kind
 
 
 def resolve_thresholds(store, hw: Optional[str] = None

@@ -47,7 +47,7 @@ Store schema (one JSON object per line):
    |"quarantine", "reason": null|"timer_floor"|"spread"|"drift_span"
    |"timeout", "spread": f|null, "reps": n, "detail": s|null}
                                                 # runtime measurement quality
-  {"kind": "calib",  "hw": backend, "low": f, "high": f, "fitted": b,
+  {"kind": "calib",  "hw": device_kind, "low": f, "high": f, "fitted": b,
    "reps": n, "samples": [{"region": r, "mode": m, "role": s, "k1": f}, ...]}
                                                 # fitted classifier thresholds
 

@@ -32,6 +32,10 @@ from repro.core import payload as payload_mod
 log = logging.getLogger("repro.controller")
 
 
+class PayloadError(RuntimeError):
+    """A (region, mode) pair's noise payload did not verify."""
+
+
 @dataclasses.dataclass(frozen=True)
 class RegionTarget:
     """One noisable region (the paper: a loop nest selected by pragma/config).
@@ -230,29 +234,27 @@ class Controller:
         of the runtime-k path holds ONE pattern in a loop body, so surviving
         ops must be counted on a static unrolled trace. Regions with a
         ``payload_check`` override (Pallas kernels) verify against their own
-        oracle instead."""
+        oracle instead.
+
+        A check that raises, or a report that fails ``ok()``, fails the
+        pair: a sweep whose noise did not run measured nothing. Returns None
+        only for regions with nothing to verify (a plain, unjitted build, or
+        a ``payload_check`` that returns None)."""
         k_chk = next((k for k in reversed(list(ks)) if k), 8)
         if target.payload_check is not None:
-            try:
-                return target.payload_check(mode, k_chk)
-            except Exception:
-                log.warning("payload check failed for %s/%s k=%d",
-                            target.name, mode, k_chk, exc_info=True)
+            rep = target.payload_check(mode, k_chk)
+        else:
+            fn = target.build(mode, k_chk)
+            if not hasattr(fn, "lower"):
                 return None
-        fn = target.build(mode, k_chk)
-        if not hasattr(fn, "lower"):
-            # expected: region builds a plain (non-jitted) callable with no
-            # .lower/.compile — measurement only, nothing to verify statically
-            return None
-        try:
             txt = fn.lower(*target.args_for(mode, k_chk)).compile().as_text()
             tgt = target.payload_target.get(mode, _default_target(mode))
-            return payload_mod.analyze_injection(txt, mode=mode, target=tgt,
-                                                 expected=k_chk)
-        except Exception:
-            log.warning("payload verification failed for %s/%s k=%d",
-                        target.name, mode, k_chk, exc_info=True)
-            return None
+            rep = payload_mod.analyze_injection(txt, mode=mode, target=tgt,
+                                                expected=k_chk)
+        if rep is not None and not rep.ok():
+            raise PayloadError(f"{target.name}/{mode} k={k_chk}: payload "
+                               f"check failed: {rep}")
+        return rep
 
     def characterize(self, target: RegionTarget,
                      modes: Sequence[str] = ("fp_add", "l1_ld", "mem_ld"),

@@ -96,8 +96,10 @@ def step_region(name: str, step_fn: Callable, args: tuple,
         return (states[mode], *args)
 
     return RegionTarget(name=name, build=build, args_for=args_for,
-                        body_size=body_size, build_rt=build_rt,
-                        args_for_rt=args_for_rt,
+                        body_size=body_size,
+                        payload_target={m: registry[m].target
+                                        for m in registry},
+                        build_rt=build_rt, args_for_rt=args_for_rt,
                         audit_hint={"scoped": True, "in_loop": False})
 
 

@@ -275,23 +275,22 @@ def _ici_state(rng, sc: NoiseScale):
     return {"v": jax.random.normal(rng, (n,), jnp.float32)}
 
 
-def _mesh_for_collectives(mesh: Optional[Any]):
+def _mesh_for_collectives(mesh: Optional[Any], axis: str):
+    """The mesh the collective runs over; an ICI mode without a mesh axis of
+    its name raises — local work in its place would feed the ``ici`` node
+    of the strategy tree with a compute measurement."""
     m = mesh if mesh is not None else compat.get_abstract_mesh()
-    if m is None or not m.axis_names:
-        return None
+    if m is None or axis not in m.axis_names:
+        raise ValueError(
+            f"ICI noise over mesh axis {axis!r} needs a mesh with that axis "
+            f"(make_modes(mesh=...) or an enclosing compat.set_mesh); found "
+            f"{'no mesh' if m is None else m.axis_names}")
     return m
-
-
-def _ici_fallback_state(v):
-    return {"c": v[:128].reshape(1, 128) * 1e-3,
-            "accs": (jnp.zeros((1, 128), jnp.float32),) * N_CHAINS}
 
 
 def _ici_allreduce_apply(state, k: int, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:   # no mesh: degrade to vpu work
-        return _fp_add_apply(_ici_fallback_state(v), k)[0], state
+    m = _mesh_for_collectives(mesh, axis)
     size = compat.mesh_axis_sizes(m)[axis]
 
     def body(x):
@@ -307,9 +306,7 @@ def _ici_allreduce_apply(state, k: int, axis: str, mesh=None):
 
 def _ici_allreduce_apply_rt(state, k, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:
-        return _fp_add_apply_rt(_ici_fallback_state(v), k)[0], state
+    m = _mesh_for_collectives(mesh, axis)
     size = compat.mesh_axis_sizes(m)[axis]
 
     def body(x, kk):   # kk replicated: runtime trip count inside the shard
@@ -324,16 +321,15 @@ def _ici_allreduce_apply_rt(state, k, axis: str, mesh=None):
 
 def _ici_allgather_apply(state, k: int, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:
-        return jnp.sum(v), state
+    m = _mesh_for_collectives(mesh, axis)
     from jax.sharding import PartitionSpec as P
 
     def body(x):  # x: local shard (n/size,)
         with jax.named_scope(NOISE_SCOPE):
             for _ in range(k):
-                g = jax.lax.all_gather(x, axis)       # (size, n/size)
-                x = jnp.mean(g, axis=0)
+                # (size, n/size); keep one row: the chain stays one
+                # collective per pattern with no local reduction
+                x = jax.lax.all_gather(x, axis)[0]
         return x
 
     out = _shard_map(body, m, P(axis), P(axis))(v)
@@ -342,16 +338,13 @@ def _ici_allgather_apply(state, k: int, axis: str, mesh=None):
 
 def _ici_allgather_apply_rt(state, k, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:
-        return jnp.sum(v), state
+    m = _mesh_for_collectives(mesh, axis)
     from jax.sharding import PartitionSpec as P
 
     def body(x, kk):
 
         def one(_, xx):
-            g = jax.lax.all_gather(xx, axis)
-            return jnp.mean(g, axis=0)
+            return jax.lax.all_gather(xx, axis)[0]
 
         with jax.named_scope(NOISE_SCOPE):
             return jax.lax.fori_loop(0, kk, one, x)
@@ -363,9 +356,7 @@ def _ici_allgather_apply_rt(state, k, axis: str, mesh=None):
 
 def _ici_a2a_apply(state, k: int, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:
-        return jnp.sum(v), state
+    m = _mesh_for_collectives(mesh, axis)
     size = compat.mesh_axis_sizes(m)[axis]
     from jax.sharding import PartitionSpec as P
 
@@ -384,9 +375,7 @@ def _ici_a2a_apply(state, k: int, axis: str, mesh=None):
 
 def _ici_a2a_apply_rt(state, k, axis: str, mesh=None):
     v = state["v"]
-    m = _mesh_for_collectives(mesh)
-    if m is None or axis not in m.axis_names:
-        return jnp.sum(v), state
+    m = _mesh_for_collectives(mesh, axis)
     size = compat.mesh_axis_sizes(m)[axis]
     from jax.sharding import PartitionSpec as P
 

@@ -18,7 +18,8 @@ import dataclasses
 from typing import Optional
 
 from repro.core.noise import NOISE_SCOPE
-from repro.hlo.parse import Instr, nesting_multipliers, find_entry, parse_module
+from repro.hlo.parse import (Instr, called_comp, find_entry,
+                             nesting_multipliers, parse_module)
 
 # Opcodes that are pure plumbing, never counted as payload or overhead.
 _BOOKKEEPING = frozenset({
@@ -48,6 +49,10 @@ class InjectionReport:
     overhead: int              # surviving non-payload noise ops
     payload_dynamic: int       # payload weighted by loop trip counts
     body_ops: int              # non-noise ops in the injected loop body |l1.l2|
+    # Pallas checks: the kernel's main output against a float32 reference,
+    # as max|out - ref| / max|ref|, and the tolerance it is held to
+    ref_err: Optional[float] = None
+    ref_tol: Optional[float] = None
 
     @property
     def survival_fraction(self) -> float:
@@ -58,9 +63,13 @@ class InjectionReport:
         tot = self.payload + self.overhead
         return self.overhead / tot if tot else 0.0
 
-    def ok(self, min_survival: float = 0.9, max_overhead: float = 0.5) -> bool:
+    def ok(self, min_survival: float = 0.9) -> bool:
+        """The payload verified: the k patterns survived and, for a Pallas
+        kernel, its main output matched the reference. Overhead is
+        reported, not gated: an ICI pattern carries one local op per
+        collective by construction."""
         return (self.survival_fraction >= min_survival
-                and self.overhead_fraction <= max_overhead)
+                and (self.ref_tol is None or self.ref_err <= self.ref_tol))
 
 
 def _is_noise(ins: Instr) -> bool:
@@ -80,13 +89,31 @@ def analyze_injection(compiled_text: str, *, mode: str, target: str,
     entry = find_entry(comps, compiled_text)
     mult = nesting_multipliers(comps, entry)
     pay_ops = PAYLOAD_OPS.get(target, PAYLOAD_OPS["compute"])
+    collectives = PAYLOAD_OPS["ici"]
+    # a collective's reduction body is part of the collective, not overhead
+    combiners = {called_comp(ins, "to_apply")
+                 for instrs in comps.values() for ins in instrs
+                 if ins.opcode in collectives}
+    # XLA may combine a noise collective with one of the step's into one
+    # tuple-shaped op tagged as the step's; its noise-tagged element reads
+    # (get-tuple-element) still name it
+    by_name = {ins.name: ins for instrs in comps.values() for ins in instrs}
+    combined = {ins.operand_names()[0] for instrs in comps.values()
+                for ins in instrs
+                if ins.opcode == "get-tuple-element" and _is_noise(ins)
+                and ins.operand_names()}
+    combined = {n for n in combined if n in by_name
+                and by_name[n].opcode in pay_ops
+                and not _is_noise(by_name[n])}
 
     payload = overhead = 0
     payload_dyn = 0
     noisy_comps: set[str] = set()
     for cname, instrs in comps.items():
+        if cname in combiners:
+            continue
         for ins in instrs:
-            if not _is_noise(ins):
+            if not (_is_noise(ins) or ins.name in combined):
                 continue
             if ins.opcode in _BOOKKEEPING or ins.opcode == "fusion":
                 continue

@@ -596,7 +596,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="threads per shard")
     pp.add_argument("--backend", default="auto",
                     choices=("auto", "interpret", "pallas"),
-                    help="pallas execution backend")
+                    help="Pallas backend: auto/pallas compile for the TPU "
+                         "and refuse to run without one; interpret runs the "
+                         "Pallas interpreter")
     pp.add_argument("--no-compile-once", action="store_true",
                     help="force the trace-per-k fallback sweep path")
     pp.add_argument("--quality-policy", default=None, metavar="JSON",
@@ -724,7 +726,10 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: Optional[Sequence[str]] = None) -> int:
     """CLI entry: dispatch to the plan/run/audit/doctor/calibrate/status/
     watch subcommand."""
+    from repro.compile_cache import setup_compile_cache
+
     args = build_parser().parse_args(argv)
+    setup_compile_cache()
     return args.fn(args)
 
 

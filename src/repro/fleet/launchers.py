@@ -9,7 +9,8 @@ or inside a deterministic fault-injection mock:
 
   * ``LocalLauncher``        — subprocess fan-out on this machine (the
     default), or sequential in-process execution for spawn-restricted
-    environments (``run --in-process``);
+    environments (``run --in-process``) and wherever this process holds
+    the accelerator (one process per chip);
   * ``SSHLauncher``          — one worker per remote host from a declarative
     ``hosts.json`` spec ({addr, python, workdir, env}); pushes the plan (and
     any partial worker store) to the host, runs the standard worker entry
@@ -204,6 +205,15 @@ def _run_worker_inline(plan_path: str, plan: SweepPlan, index: int) -> int:
 # ---------------------------------------------------------------------------
 
 
+def holds_accelerator() -> bool:
+    """Whether this process runs JAX on an accelerator. A chip belongs to
+    one process at a time, so a child that needs it fails or hangs while
+    this process lives."""
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 class LocalLauncher(Launcher):
     """Workers on THIS machine.
 
@@ -213,6 +223,8 @@ class LocalLauncher(Launcher):
     fan-out's price and ``SSHLauncher`` is the escape), output streamed
     line-prefixed. ``in_process=True`` runs shards sequentially inside this
     process instead — for spawn-restricted environments and fast tests.
+    Where this process holds an accelerator (``holds_accelerator``), shards
+    always run here, one after another: the chip is this process's.
     """
 
     def __init__(self, *, in_process: bool = False):
@@ -226,7 +238,11 @@ class LocalLauncher(Launcher):
                attempts: Optional[Mapping[int, int]] = None
                ) -> dict[int, ShardOutcome]:
         """Spawn (or inline-run) every index; see class docstring."""
-        if self.in_process:
+        inline = self.in_process or holds_accelerator()
+        if inline and not self.in_process:
+            print("== local shards run in this process, one after another: "
+                  "it holds the accelerator", flush=True)
+        if inline:
             return {i: ShardOutcome(_run_worker_inline(plan_path, plan, i))
                     for i in indices}
         procs: dict[int, tuple] = {}

@@ -178,7 +178,7 @@ _CONST_RE = re.compile(r"constant\((\-?\d+)\)")
 _TRIP_RE = re.compile(r'known_trip_count[^0-9]*"?n"?[^0-9]*(\d+)')
 
 
-def _called_comp(instr: Instr, key: str) -> Optional[str]:
+def called_comp(instr: Instr, key: str) -> Optional[str]:
     m = re.search(key + r"=%?([\w.\-]+)", instr.line)
     return m.group(1) if m else None
 
@@ -201,7 +201,7 @@ def while_trip_counts(comps: dict[str, list[Instr]]) -> dict[str, int]:
             if m:
                 trip = int(m.group(1))
             else:
-                cond = _called_comp(ins, "condition")
+                cond = called_comp(ins, "condition")
                 if cond and cond in comps:
                     consts = [int(x) for i in comps[cond]
                               for x in _CONST_RE.findall(i.line)]
@@ -228,8 +228,8 @@ def nesting_multipliers(comps: dict[str, list[Instr]],
         for ins in comps[cname]:
             if ins.opcode == "while":
                 t = trips.get(ins.name, 1)
-                body = _called_comp(ins, "body")
-                cond = _called_comp(ins, "condition")
+                body = called_comp(ins, "body")
+                cond = called_comp(ins, "condition")
                 if body:
                     visit(body, m * t)
                 if cond:
@@ -240,7 +240,7 @@ def nesting_multipliers(comps: dict[str, list[Instr]],
                                 "reduce-scatter", "select-and-scatter"):
                 for key in ("calls", "to_apply", "body", "branch_computations",
                             "called_computations"):
-                    sub = _called_comp(ins, key)
+                    sub = called_comp(ins, key)
                     if sub:
                         visit(sub, m)
                 # conditional: parse brace list {%a, %b}
@@ -263,7 +263,7 @@ def find_entry(comps: dict[str, list[Instr]], text: str) -> str:
     for instrs in comps.values():
         for ins in instrs:
             for key in ("calls", "to_apply", "body", "condition"):
-                c = _called_comp(ins, key)
+                c = called_comp(ins, key)
                 if c:
                     called.add(c)
     for name in comps:

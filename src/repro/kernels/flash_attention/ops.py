@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpreter
 from repro.kernels.flash_attention.kernel import (flash_attention_pallas,
                                                   flash_attention_pallas_rt)
 from repro.kernels.flash_attention.ref import attention_ref
@@ -21,15 +22,13 @@ def flash_attention(q, k, v, noise=None, *, causal: bool = True,
     """Blocked attention. Returns (out, nacc)."""
     if noise is None:
         noise = default_noise_operand(jnp.float32)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     if backend == "ref":
         return (attention_ref(q, k, v, causal=causal, window=window),
                 jnp.zeros((8, 128), jnp.float32))
     return flash_attention_pallas(q, k, v, noise, causal=causal,
                                   window=window, bq=bq, bk=bk, mode=mode,
                                   k_noise=k_noise,
-                                  interpret=(backend == "interpret"))
+                                  interpret=use_interpreter(backend))
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk", "mode",
@@ -42,8 +41,6 @@ def flash_attention_rt(kq, q, k, v, noise=None, *, causal: bool = True,
     ``flash_attention(..., k_noise=kq)`` for kq ≤ noise_slots.K_MAX."""
     if noise is None:
         noise = default_noise_operand(jnp.float32)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return flash_attention_pallas_rt(kq, q, k, v, noise, causal=causal,
                                      window=window, bq=bq, bk=bk, mode=mode,
-                                     interpret=(backend == "interpret"))
+                                     interpret=use_interpreter(backend))

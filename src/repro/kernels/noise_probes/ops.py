@@ -5,6 +5,7 @@ from functools import partial
 
 import jax
 
+from repro.kernels.backend import use_interpreter
 from repro.kernels.noise_probes.kernel import probe_pallas, probe_pallas_rt
 from repro.kernels.noise_probes.ref import probe_ref
 from repro.kernels.noisy_matmul.ops import default_noise_operand
@@ -15,12 +16,10 @@ def run_probe(noise=None, *, mode: str = "fp", k_noise: int = 1,
               n_steps: int = 128, backend: str = "auto"):
     if noise is None:
         noise = default_noise_operand()
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     if backend == "ref":
         return probe_ref(noise, mode=mode, k_noise=k_noise, n_steps=n_steps)
     return probe_pallas(noise, mode=mode, k_noise=k_noise, n_steps=n_steps,
-                        interpret=(backend == "interpret"))
+                        interpret=use_interpreter(backend))
 
 
 @partial(jax.jit, static_argnames=("mode", "n_steps", "backend"))
@@ -30,7 +29,5 @@ def run_probe_rt(k, noise=None, *, mode: str = "fp", n_steps: int = 128,
     per-pattern-cost sweep reuses one executable per mode."""
     if noise is None:
         noise = default_noise_operand()
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return probe_pallas_rt(k, noise, mode=mode, n_steps=n_steps,
-                           interpret=(backend == "interpret"))
+                           interpret=use_interpreter(backend))

@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpreter
 from repro.kernels.noise_slots import NOISE_REF_SHAPE
 from repro.kernels.noisy_matmul.kernel import matmul_pallas, matmul_pallas_rt
 from repro.kernels.noisy_matmul.ref import matmul_ref
@@ -23,19 +24,17 @@ def noisy_matmul(a, b, noise=None, *, mode: str = "none", k_noise: int = 0,
                  backend: str = "auto"):
     """Matmul with optional kernel-level noise.
 
-    backend: "pallas" (TPU), "interpret" (CPU validation), "ref" (oracle),
-    "auto" (pallas on TPU, interpret elsewhere).
+    backend: "pallas"/"auto" (the TPU; raises without one), "interpret"
+    (the Pallas interpreter, any backend), "ref" (oracle).
     Returns (out, nacc); nacc is zeros for mode="none".
     """
     if noise is None:
         noise = default_noise_operand(a.dtype)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     if backend == "ref":
         return matmul_ref(a, b), jnp.zeros((8, 128), jnp.float32)
     return matmul_pallas(a, b, noise, mode=mode, k_noise=k_noise,
                          bm=bm, bn=bn, bk=bk,
-                         interpret=(backend == "interpret"))
+                         interpret=use_interpreter(backend))
 
 
 @partial(jax.jit, static_argnames=("mode", "bm", "bn", "bk", "backend"))
@@ -47,7 +46,5 @@ def noisy_matmul_rt(k, a, b, noise=None, *, mode: str = "fp",
     for k ≤ noise_slots.K_MAX."""
     if noise is None:
         noise = default_noise_operand(a.dtype)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return matmul_pallas_rt(k, a, b, noise, mode=mode, bm=bm, bn=bn, bk=bk,
-                            interpret=(backend == "interpret"))
+                            interpret=use_interpreter(backend))

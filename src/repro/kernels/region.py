@@ -21,12 +21,17 @@ the instruction-granularity Pallas kernels on the same spine:
     oracle — stronger than op counting, since the accumulated value pins
     both that ALL k patterns executed and that none was duplicated.
 
-Backends: "interpret" (CPU validation — the container has no TPU; also what
-benchmarks/CI drive), "pallas" (real TPU), "auto".
+Backends (``repro.kernels.backend``): "pallas" and "auto" compile the kernel
+with Mosaic and refuse to build a region where JAX finds no TPU;
+"interpret" runs the kernel body in the Pallas interpreter, for tests and
+docs. On the chip the payload check also holds the kernel's main output to
+a float32 reference (``REF_TOL``), and the static audit leaves the region
+out: the Mosaic body is one opaque ``tpu_custom_call`` in the HLO.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -36,8 +41,10 @@ import numpy as np
 from repro.core.controller import RegionTarget
 from repro.core.payload import InjectionReport
 from repro.kernels import noise_slots as ns
+from repro.kernels.backend import use_interpreter
 from repro.kernels.flash_attention.kernel import (flash_attention_pallas,
                                                   flash_attention_pallas_rt)
+from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.noise_probes.kernel import probe_pallas, probe_pallas_rt
 from repro.kernels.noise_probes.ref import probe_ref
 from repro.kernels.noisy_matmul.kernel import matmul_pallas, matmul_pallas_rt
@@ -80,6 +87,23 @@ def validate_size(kernel: str, n: int) -> None:
 # which resource one pattern of each kernel mode stresses (payload reports)
 MODE_TARGETS = {"fp": "compute", "mxu": "compute", "vmem": "vmem"}
 
+# max|out - ref| / max|ref| the payload check allows the kernel's main
+# output against its float32 reference: the MXU kernels may take single-pass
+# bf16 products of f32 operands (~2e-3 at n=4096); SPMXV is f32 VPU work
+REF_TOL = {"matmul": 1e-2, "spmxv": 1e-5, "attention": 1e-2}
+
+# the static payload check runs at most this many patterns: its nacc oracle
+# holds only up to float32 summation error, which grows with the adds one
+# accumulator lane takes (k x grid steps), and a smaller k compiles faster
+CHECK_K_MAX = 16
+
+
+def oracle_rtol(n_adds: int) -> float:
+    """Relative tolerance of an nacc oracle after ``n_adds`` sequential
+    float32 adds: the recursive-summation bound n·u, u = 2^-24. At
+    ``CHECK_K_MAX`` it stays below the 1/k a missing pattern would cost."""
+    return max(1e-4, n_adds * 2.0 ** -24)
+
 
 # region-name derivation, shared by the spec builders below and by
 # ``family_names`` (cheap grid queries — fleet status/inspect must learn a
@@ -116,6 +140,8 @@ class _KernelSpec:
     oracle: Callable[[str, int], Optional[jnp.ndarray]]
     n_steps: int                                # grid steps visiting the slot
     body_size: int                              # |l1.l2| stand-in for Abs^rel
+    # () -> float32 reference of the main output; None: no main output
+    reference: Optional[Callable[[], np.ndarray]] = None
 
 
 def _matmul_spec(interpret: bool, *, n: int = 256, bm: int = 128,
@@ -141,8 +167,12 @@ def _matmul_spec(interpret: bool, *, n: int = 256, bm: int = 128,
             return ns.expected_fp_noise(noise, k, grid_steps)
         return None
 
+    def reference():
+        return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
     return _KernelSpec(_matmul_name(n=n), (a, b, noise), static_fn,
-                       rt_fn, oracle, grid_steps, body_size=3)
+                       rt_fn, oracle, grid_steps, body_size=3,
+                       reference=reference)
 
 
 def _spmxv_spec(interpret: bool, *, n: int = 512, nnz_per_row: int = 16,
@@ -168,9 +198,13 @@ def _spmxv_spec(interpret: bool, *, n: int = 512, nnz_per_row: int = 16,
             return vmem_noise_ell_ref(vals, k, br)
         return None
 
+    def reference():     # NumPy, float64 accumulation
+        v, c, xx = (np.asarray(t) for t in (vals, cols, x))
+        return (v.astype(np.float64) * xx.astype(np.float64)[c]).sum(axis=1)
+
     return _KernelSpec(_spmxv_name(n=n, nnz_per_row=nnz_per_row, q=q),
                        (vals, cols, x), static_fn, rt_fn, oracle, nb,
-                       body_size=4)
+                       body_size=4, reference=reference)
 
 
 def _attention_spec(interpret: bool, *, batch: int = 1, heads: int = 2,
@@ -206,10 +240,15 @@ def _attention_spec(interpret: bool, *, batch: int = 1, heads: int = 2,
             return ns.expected_fp_noise(noise, kn, grid_steps)
         return None
 
+    def reference():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(attention_ref, static_argnames="causal")(
+                q, k, v, causal=causal)
+
     return _KernelSpec(_attention_name(batch=batch, heads=heads, seq=seq,
                                        head_dim=head_dim),
                        (q, k, v, noise), static_fn, rt_fn, oracle,
-                       grid_steps, body_size=12)
+                       grid_steps, body_size=12, reference=reference)
 
 
 def _probe_spec(interpret: bool, *, n_steps: int = 64) -> _KernelSpec:
@@ -257,8 +296,7 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
     if kernel not in _SPECS:
         raise ValueError(f"unknown pallas kernel {kernel!r}; "
                          f"one of {sorted(_SPECS)}")
-    interpret = (backend == "interpret"
-                 or (backend == "auto" and jax.default_backend() != "tpu"))
+    interpret = use_interpreter(backend)
     spec = _SPECS[kernel](interpret, **sizes)
     modes = KERNEL_MODES[kernel]
 
@@ -293,22 +331,38 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
     def args_for_rt(mode: str):
         return spec.args
 
-    def payload_check(mode: str, k: int) -> Optional[InjectionReport]:
+    @functools.cache
+    def reference() -> np.ndarray:        # once per region, every mode
+        return np.asarray(spec.reference(), np.float64)
+
+    def payload_check(mode: str, k: int) -> InjectionReport:
         """Arithmetic-level static payload check: run the static-k build
         once; an exact oracle match (or a nonzero accumulator for modes
-        without a closed-form oracle) proves all k patterns executed."""
+        without a closed-form oracle) proves all k patterns executed. The
+        same run's main output is compared with the float32 reference.
+        Checks at most ``CHECK_K_MAX`` patterns."""
         _check_mode(mode)
-        nacc = np.asarray(_nacc_of(build(mode, k)(*spec.args)), np.float32)
+        k = min(k, CHECK_K_MAX)
+        result = build(mode, k)(*spec.args)
+        nacc = np.asarray(_nacc_of(result), np.float32)
         want = spec.oracle(mode, k)
         if want is not None:
             ok = np.allclose(nacc, np.asarray(want, np.float32),
-                             rtol=1e-4, atol=1e-5)
+                             rtol=oracle_rtol(k * spec.n_steps), atol=1e-5)
         else:
             ok = bool(np.abs(nacc).sum() > 0) if k else True
+        ref_err = ref_tol = None
+        if spec.reference is not None:
+            ref = reference()
+            out = np.asarray(result[0], np.float64).reshape(ref.shape)
+            ref_err = float(np.max(np.abs(out - ref))
+                            / max(float(np.max(np.abs(ref))), 1e-30))
+            ref_tol = REF_TOL[kernel]
         return InjectionReport(
             mode=mode, target=MODE_TARGETS[mode], expected=k,
             payload=k if ok else 0, overhead=0,
-            payload_dynamic=k * spec.n_steps, body_ops=spec.body_size)
+            payload_dynamic=k * spec.n_steps, body_ops=spec.body_size,
+            ref_err=ref_err, ref_tol=ref_tol)
 
     return RegionTarget(name=name or spec.name, build=build,
                         args_for=args_for, body_size=spec.body_size,
@@ -317,9 +371,11 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
                         payload_check=payload_check,
                         # Pallas bodies lose named-scope metadata in
                         # lowering: the audit censuses everything and lets
-                        # the two-point k-delta isolate the noise
+                        # the two-point k-delta isolate the noise; a Mosaic
+                        # body is opaque to the census altogether
                         audit_hint={"scoped": False, "in_loop": True,
-                                    "steps": spec.n_steps})
+                                    "steps": spec.n_steps,
+                                    "opaque": not interpret})
 
 
 def family_params(kernel: str) -> frozenset:

@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import use_interpreter
 from repro.kernels.spmv_ell.kernel import spmv_ell_pallas, spmv_ell_pallas_rt
 from repro.kernels.spmv_ell.ref import spmv_ell_ref
 
@@ -14,12 +15,10 @@ from repro.kernels.spmv_ell.ref import spmv_ell_ref
 def spmv_ell(vals, cols, x, *, br: int = 128, mode: str = "none",
              k_noise: int = 0, backend: str = "auto"):
     """ELL SPMV. Returns (y (R,), nacc (8,128))."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     if backend == "ref":
         return spmv_ell_ref(vals, cols, x), jnp.zeros((8, 128), jnp.float32)
     return spmv_ell_pallas(vals, cols, x, br=br, mode=mode, k_noise=k_noise,
-                           interpret=(backend == "interpret"))
+                           interpret=use_interpreter(backend))
 
 
 @partial(jax.jit, static_argnames=("br", "mode", "backend"))
@@ -28,7 +27,5 @@ def spmv_ell_rt(k, vals, cols, x, *, br: int = 128, mode: str = "fp",
     """Runtime-k ELL SPMV: ``k`` is a traced int32 operand (compile-once
     sweeps), pattern-identical to ``spmv_ell(..., k_noise=k)`` for
     k ≤ noise_slots.K_MAX."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "interpret"
     return spmv_ell_pallas_rt(k, vals, cols, x, br=br, mode=mode,
-                              interpret=(backend == "interpret"))
+                              interpret=use_interpreter(backend))

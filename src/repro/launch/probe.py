@@ -26,12 +26,14 @@ workload classify separately (docs/methodology.md §Serving):
         --seq 16 --batch 4 [--modes fp_add32,hbm_stream] [--store PATH]
 
 Pallas mode probes one of the real kernels (matmul / spmxv / attention /
-probe; interpret mode off-TPU) through the SAME campaign machinery — the
-noise quantity is a runtime operand of the kernel itself, so the whole
-sweep compiles ≤2 Pallas executables per mode:
+probe) through the SAME campaign machinery — the noise quantity is a
+runtime operand of the kernel itself, so the whole sweep compiles ≤2 Pallas
+executables per mode. It runs on the TPU; ``--backend interpret`` runs the
+kernels in the Pallas interpreter instead (tests, docs):
 
     PYTHONPATH=src python -m repro.launch.probe --pallas spmxv \
-        [--modes fp,vmem] [--store PATH] [--expect-no-measure]
+        [--backend interpret] [--modes fp,vmem] [--store PATH] \
+        [--expect-no-measure]
 
 Fleet worker mode executes a slice of a saved ``SweepPlan`` — this is what
 ``python -m repro.fleet run`` spawns (through any of its launchers: local
@@ -134,7 +136,7 @@ def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
                workers: int, compile_once: bool,
                shard: Optional[tuple[int, int]], expect_no_measure: bool,
                header: str, audit: str = "gate",
-               quality: str = "gate") -> None:
+               quality: str = "gate", backend: str = "auto") -> None:
     """Build a one-target SweepPlan from CLI flags and execute it through
     the fleet worker — the campaign tail (store naming, shard dispatch,
     reporting) lives behind that API now."""
@@ -144,7 +146,7 @@ def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
     plan = SweepPlan(name=header, store=store or "", targets=[spec],
                      reps=reps, shards=(shard[1] if shard else 1),
                      workers=workers, compile_once=compile_once,
-                     backend="auto")
+                     backend=backend)
     if not plan.store:
         first = plan.resolve()[0][1][0]
         plan.store = os.path.join(CAMPAIGN_DIR, f"{first.name}.jsonl")
@@ -218,10 +220,12 @@ def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
                  compile_once: bool = True,
                  shard: Optional[tuple[int, int]] = None,
                  expect_no_measure: bool = False,
-                 audit: str = "gate", quality: str = "gate") -> None:
-    """Run the paper's methodology against a real Pallas kernel (interpret
-    mode off-TPU). The sweep rides the compile-once runtime-k path: ≤2
-    Pallas executables per (kernel, mode)."""
+                 audit: str = "gate", quality: str = "gate",
+                 backend: str = "auto") -> None:
+    """Run the paper's methodology against a real Pallas kernel (on the
+    TPU, or in the interpreter with ``backend="interpret"``). The sweep
+    rides the compile-once runtime-k path: ≤2 Pallas executables per
+    (kernel, mode)."""
     from repro.kernels.region import KERNEL_MODES, SIZE_DEFAULT, validate_size
 
     if kernel not in KERNEL_MODES:
@@ -246,7 +250,8 @@ def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
     _run_adhoc(spec, reps=reps, store=store, fresh=fresh, workers=workers,
                compile_once=compile_once, shard=shard,
                expect_no_measure=expect_no_measure, audit=audit,
-               quality=quality, header=f"pallas probe: {kernel}")
+               quality=quality, backend=backend,
+               header=f"pallas probe: {kernel}")
 
 
 def plan_probe(plan_path: str, *, shard: Optional[tuple[int, int]],
@@ -371,8 +376,13 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--pallas", default=None,
                     metavar="{matmul,spmxv,attention,probe}",
                     help="probe a Pallas kernel region instead of a model "
-                         "step (interpret mode off-TPU; modes default to "
-                         "the kernel's fp/mxu/vmem set)")
+                         "step (modes default to the kernel's fp/mxu/vmem "
+                         "set)")
+    ap.add_argument("--backend", default="auto",
+                    choices=("auto", "pallas", "interpret"),
+                    help="Pallas backend under --pallas: auto/pallas compile "
+                         "for the TPU and refuse to run without one; "
+                         "interpret runs the Pallas interpreter")
     ap.add_argument("--pallas-n", type=int, default=None,
                     help="kernel size knob (rows for matmul/spmxv, seq for "
                          "attention, grid steps for probe)")
@@ -426,7 +436,10 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv: Optional[Sequence[str]] = None) -> None:
     """CLI entry: route the flags to the measured / analytic / pallas /
     fleet-worker probe path."""
+    from repro.compile_cache import setup_compile_cache
+
     args = build_parser().parse_args(argv)
+    setup_compile_cache()
 
     modes = ([m.strip() for m in args.modes.split(",") if m.strip()]
              if args.modes else None)
@@ -442,7 +455,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
             ("--workers", args.workers != 1),
             ("--no-compile-once", args.no_compile_once),
             ("--kind", args.kind != "train"), ("--seq", args.seq != 128),
-            ("--batch", args.batch != 4)) if given]
+            ("--batch", args.batch != 4),
+            ("--backend", args.backend != "auto")) if given]
         if overridden:
             raise SystemExit("--plan carries its own targets, modes and "
                              "settings; drop the conflicting flag(s): "
@@ -459,7 +473,8 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                      workers=args.workers,
                      compile_once=not args.no_compile_once, shard=shard,
                      expect_no_measure=args.expect_no_measure,
-                     audit=args.audit, quality=args.quality)
+                     audit=args.audit, quality=args.quality,
+                     backend=args.backend)
         return
     if args.arch is None:
         raise SystemExit("--arch is required unless --pallas or --plan "

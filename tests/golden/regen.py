@@ -157,7 +157,7 @@ def replay(store_path: str) -> dict:
 # ---------------------------------------------------------------------------
 # Golden HLO audit fixtures: all four Pallas kernels + one loop region.
 # Small sizes keep the gzipped texts a few hundred KB total; `interpret`
-# keeps the compiles host-runnable on both CI pins.
+# keeps the compiles host-runnable without a chip.
 # ---------------------------------------------------------------------------
 
 HLO_DIR = os.path.join(HERE, "hlo")

@@ -1,23 +1,34 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps,
 exact noise-payload accounting, and static-k vs runtime-k equivalence
 (bitwise) for every kernel and noise mode."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.ops import (flash_attention,
-                                               flash_attention_rt)
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.noise_probes.ops import run_probe, run_probe_rt
+from repro.kernels.noise_probes import ops as probe_ops
 from repro.kernels.noise_probes.ref import probe_ref
 from repro.kernels.noise_slots import K_MAX
-from repro.kernels.noisy_matmul.ops import (default_noise_operand,
-                                            noisy_matmul, noisy_matmul_rt)
+from repro.kernels.noisy_matmul import ops as mm_ops
+from repro.kernels.noisy_matmul.ops import default_noise_operand
 from repro.kernels.noisy_matmul.ref import fp_noise_ref, matmul_ref
-from repro.kernels.spmv_ell.ops import spmv_ell, spmv_ell_rt
+from repro.kernels.spmv_ell import ops as spmv_ops
 from repro.kernels.spmv_ell.ref import (fp_noise_ell_ref, make_band_ell,
                                         spmv_ell_ref, vmem_noise_ell_ref)
+
+# every kernel here runs in the Pallas interpreter, by name
+flash_attention = partial(fa_ops.flash_attention, backend="interpret")
+flash_attention_rt = partial(fa_ops.flash_attention_rt, backend="interpret")
+run_probe = partial(probe_ops.run_probe, backend="interpret")
+run_probe_rt = partial(probe_ops.run_probe_rt, backend="interpret")
+noisy_matmul = partial(mm_ops.noisy_matmul, backend="interpret")
+noisy_matmul_rt = partial(mm_ops.noisy_matmul_rt, backend="interpret")
+spmv_ell = partial(spmv_ops.spmv_ell, backend="interpret")
+spmv_ell_rt = partial(spmv_ops.spmv_ell_rt, backend="interpret")
 
 
 @pytest.mark.parametrize("M,N,K", [(256, 256, 256), (512, 256, 384),

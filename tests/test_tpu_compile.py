@@ -1,0 +1,73 @@
+"""Every Pallas kernel × noise mode, static-k and runtime-k, compiled by the
+TPU compiler (Mosaic) for a described v5e chip at the sizes
+``chip_smoke.py`` campaigns at. Nothing runs: a compile that the chip's
+compiler refuses fails here, at no chip time.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels.region import _SPECS, KERNEL_MODES
+
+# kernel -> the spec sizes of chip_smoke.py's campaign
+REAL_SIZES = {
+    "matmul": {"n": 4096},
+    "spmxv": {"n": 1 << 18, "nnz_per_row": 16, "q": 1.0},
+    "attention": {"batch": 1, "heads": 8, "kv_heads": 1, "seq": 4096,
+                  "head_dim": 256},
+    "probe": {"n_steps": 64},
+}
+CASES = [(kernel, mode, path) for kernel in sorted(KERNEL_MODES)
+         for mode in KERNEL_MODES[kernel] for path in ("static", "rt")]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from the persistent
+    # cache without one; keep them out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def specs():
+    """The campaign's kernel specs (argument shapes, static and runtime-k
+    callables), built once per kernel for the chip (interpret=False)."""
+    return {kernel: _SPECS[kernel](False, **sizes)
+            for kernel, sizes in REAL_SIZES.items()}
+
+
+@pytest.mark.parametrize("kernel,mode,path", CASES,
+                         ids=[f"{k}-{m}-{p}" for k, m, p in CASES])
+def test_kernel_compiles_for_v5e(topo, specs, kernel, mode, path):
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    spec = specs[kernel]
+    shapes = [jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=one_chip)
+              for a in spec.args]
+    if path == "static":
+        fn = spec.static_fn(mode, 5)
+    else:
+        fn = spec.rt_fn(mode)
+        shapes = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+                  *shapes]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
