@@ -14,9 +14,11 @@ One process does all of it (a chip belongs to one process at a time):
    reference within ``repro.kernels.region.REF_TOL``.
 2. Gemma-2B serve: the full-width config with random weights from
    ``--seed``, a paged ``ServeEngine`` with 4 slots answering 8 requests
-   (prompts of 16 to 128 tokens, 16 new tokens each). The first-token
-   logits of every batched prefill are held to a float32 forward pass of
-   the same prompts within ``GEMMA_LOGIT_TOL``.
+   (prompts of 16 to 128 tokens, 16 new tokens each). A float32 forward
+   of each prompt and its served tokens is the reference: the first token
+   the engine served must be its argmax, the prefill's first-token logits
+   must lie within ``GEMMA_LOGIT_TOL`` of it, and every decoded token must
+   sit within ``TOKEN_MARGIN`` of its position's float32 maximum.
 3. decode-tick probe: the engine's decode tick wrapped with graph-level
    noise (``core.injector.step_region``), ``hbm_stream`` and ``fp_add32``
    swept over a short k grid by the ``Controller``; both payloads must
@@ -55,16 +57,22 @@ KERNEL_TARGETS = {
                   "heads": 8, "kv_heads": 1, "head_dim": 256,
                   "modes": ["fp", "mxu", "vmem"]},
 }
+KERNEL_REPS = 2
 # the serve workload
 N_SLOTS, N_REQUESTS, MAX_NEW = 4, 8, 16
 PROMPT_LENS = (16, 128)              # inclusive range of prompt lengths
 MAX_SEQ, PAGE_SIZE = 256, 16
 # max|logits - ref| / max|ref| of bf16 serving against the f32 forward
 GEMMA_LOGIT_TOL = 5e-2
+# how far below the f32 maximum (over max|logit|) a served token's f32
+# logit may sit: bf16 and f32 may each be off by the tolerance at a near-tie
+TOKEN_MARGIN = 2 * GEMMA_LOGIT_TOL
 DECODE_MODES = ("hbm_stream", "fp_add32")
 DECODE_KS = (0, 4, 16, 64)
+PROBE_REPS = 3                       # decode-tick and ICI sweeps
 ICI_MODES = ("ici_allreduce", "ici_allgather", "ici_a2a")
 ICI_KS = (0, 2, 4, 8)
+ICI_MLP = (1024, 4096, 64)           # d_model, d_ff, batch of the TP step
 # the HLO opcode each ICI mode must compile to
 ICI_OPCODE = {"ici_allreduce": "all-reduce", "ici_allgather": "all-gather",
               "ici_a2a": "all-to-all"}
@@ -132,22 +140,20 @@ def require_tpu(n_chips: int):
 # phase 1: the kernel campaign through `fleet run`
 # ---------------------------------------------------------------------------
 
-def kernel_campaign(out_dir: Path, clock: CompileClock, *,
-                    targets=KERNEL_TARGETS, backend: str = "pallas",
-                    reps: int = 2) -> dict:
+def kernel_campaign(out_dir: Path, clock: CompileClock) -> dict:
     """One fleet plan per kernel family, each run fresh through
     ``run_fleet``; returns {family: {region: per-mode payload results}}."""
     from repro.fleet.executor import run_fleet
     from repro.fleet.plan import SweepPlan, TargetSpec
 
     out: dict = {}
-    for family, spec in targets.items():
+    for family, spec in KERNEL_TARGETS.items():
         params = {k: v for k, v in spec.items() if k != "modes"}
         plan = SweepPlan(name=f"chip_smoke_{family}",
                          store=str(out_dir / f"{family}.jsonl"),
                          targets=[TargetSpec("pallas", tuple(spec["modes"]),
                                              params)],
-                         reps=reps, shards=1, backend=backend)
+                         reps=KERNEL_REPS, shards=1, backend="pallas")
         path = plan.save(str(out_dir / f"{family}.plan.json"))
         times: dict = {}
         with clock.phase(f"kernels/{family}", times):
@@ -180,9 +186,9 @@ def kernel_campaign(out_dir: Path, clock: CompileClock, *,
 # ---------------------------------------------------------------------------
 
 def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
-    """Serve ``N_REQUESTS`` random prompts on a paged engine; check every
-    admission wave's first-token logits against a float32 forward. Returns
-    (summary, decode-tick cell snapshot)."""
+    """Serve ``N_REQUESTS`` random prompts on a paged engine and hold what
+    it served to a float32 forward. Returns (summary, decode-tick cell
+    snapshot)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -204,18 +210,21 @@ def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
     cfg32 = cfg.scaled(compute_dtype="float32")
 
     @jax.jit
-    def served_logits(p, cache, toks, rows, lens):
-        # the engine's batched prefill program, logits kept
+    def prefill_logits(p, cache, toks, rows, lens):
+        # the forward of the engine's batched prefill, which returns only
+        # the sampled tokens, with its last-position logits kept
         logits, _ = tf.lm_paged_prefill(p, cfg, {"tokens": toks}, cache,
                                         rows)
         return jnp.take_along_axis(
             logits, (lens - 1)[:, None, None], axis=1)[:, 0]
 
     @jax.jit
-    def ref_logits(p, toks, lens):
+    def ref_logits(p, toks, first):
+        # float32 forward of prompt + served tokens (teacher forcing): the
+        # logits that chose each of the MAX_NEW served tokens
         logits, _ = tf.lm_forward(p, cfg32, {"tokens": toks})
-        return jnp.take_along_axis(
-            logits, (lens - 1)[:, None, None], axis=1)[:, 0]
+        at = first[:, None] + jnp.arange(MAX_NEW)[None, :]
+        return jnp.take_along_axis(logits, at[:, :, None], axis=1)
 
     eng = ServeEngine(api, params, n_slots=N_SLOTS, max_seq=MAX_SEQ,
                       page_size=PAGE_SIZE, seed=seed)
@@ -226,7 +235,7 @@ def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
                                     ).tolist(), max_new=MAX_NEW)
             for _ in range(N_REQUESTS)]
 
-    errs, agree, rows_checked = [], 0, 0
+    served: dict = {}                   # id(request) -> prefill logits
     waves, seen, tick_cell, ticks_since = 0, None, None, 0
     with clock.phase("serve/run", times):
         for _ in range(10 * N_REQUESTS * MAX_NEW):
@@ -237,16 +246,10 @@ def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
             _, cache, toks, rows, lens, adm = pf_args[:6]
             if toks is not seen:                    # a new admission wave
                 seen, waves, ticks_since = toks, waves + 1, 0
-                got = np.asarray(served_logits(params, cache, toks, rows,
-                                               lens), np.float64)
-                with jax.default_matmul_precision("highest"):
-                    want = np.asarray(ref_logits(params, toks, lens),
-                                      np.float64)
+                got = np.asarray(prefill_logits(params, cache, toks, rows,
+                                                lens), np.float64)
                 for b in np.flatnonzero(np.asarray(adm)):
-                    errs.append(float(np.max(np.abs(got[b] - want[b]))
-                                      / np.max(np.abs(want[b]))))
-                    agree += int(np.argmax(got[b]) == np.argmax(want[b]))
-                    rows_checked += 1
+                    served[id(eng.slot_req[b])] = got[b]
             else:
                 ticks_since += 1
             if waves == 2 and ticks_since == 2 and tick_cell is None:
@@ -255,24 +258,57 @@ def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
     if len(done) != N_REQUESTS or any(len(r.out) != MAX_NEW for r in reqs):
         raise SmokeError(f"serve: {len(done)}/{N_REQUESTS} requests done, "
                          f"lengths {[len(r.out) for r in reqs]}")
-    if rows_checked != N_REQUESTS:
-        raise SmokeError(f"serve: compared {rows_checked} prefill rows, "
-                         f"want {N_REQUESTS}")
-    worst = max(errs)
+    if sorted(served) != sorted(id(r) for r in reqs):
+        raise SmokeError(f"serve: prefill logits of {len(served)} requests "
+                         f"captured, want {N_REQUESTS}")
+
+    with clock.phase("serve/reference", times):
+        want = []
+        for i in range(0, N_REQUESTS, N_SLOTS):
+            batch = reqs[i:i + N_SLOTS]
+            toks = np.zeros((len(batch), PROMPT_LENS[1] + MAX_NEW), np.int32)
+            for b, r in enumerate(batch):
+                seq = r.prompt + r.out[:-1]
+                toks[b, :len(seq)] = seq
+            first = np.array([len(r.prompt) - 1 for r in batch], np.int32)
+            with jax.default_matmul_precision("highest"):
+                want.extend(np.asarray(ref_logits(params, toks, first),
+                                       np.float64))
+
+    logit_errs, first_agree, tok_agree, gaps = [], 0, 0, []
+    for r, w in zip(reqs, want):        # w: (MAX_NEW, vocab)
+        logit_errs.append(float(np.max(np.abs(served[id(r)] - w[0]))
+                                / np.max(np.abs(w[0]))))
+        first_agree += int(r.out[0] == int(np.argmax(w[0])))
+        for j, tok in enumerate(r.out):
+            tok_agree += int(tok == int(np.argmax(w[j])))
+            gaps.append(float((np.max(w[j]) - w[j][tok])
+                              / np.max(np.abs(w[j]))))
+    worst, worst_gap = max(logit_errs), max(gaps)
     print(f"  {N_REQUESTS}/{N_REQUESTS} requests answered in {waves} "
-          f"admission waves, {eng.stats['ticks']} decode ticks; first-token "
-          f"logits vs f32 forward: max normalized error {worst:.4g} "
-          f"(tol {GEMMA_LOGIT_TOL:g}), argmax agrees on {agree}/"
-          f"{rows_checked}", flush=True)
+          f"admission waves, {eng.stats['ticks']} decode ticks; against a "
+          f"float32 forward: first token = argmax on {first_agree}/"
+          f"{N_REQUESTS}, prefill logits max normalized error {worst:.4g} "
+          f"(tol {GEMMA_LOGIT_TOL:g}), all served tokens = argmax on "
+          f"{tok_agree}/{len(gaps)}, largest gap below the maximum "
+          f"{worst_gap:.4g} (margin {TOKEN_MARGIN:g})", flush=True)
+    if first_agree != N_REQUESTS:
+        raise SmokeError(f"serve: the first served token is the f32 argmax "
+                         f"on only {first_agree}/{N_REQUESTS} requests")
     if worst > GEMMA_LOGIT_TOL:
         raise SmokeError(f"serve: prefill logits differ from the f32 forward "
                          f"by {worst:.4g} > {GEMMA_LOGIT_TOL:g}")
+    if worst_gap > TOKEN_MARGIN:
+        raise SmokeError(f"serve: a served token sits {worst_gap:.4g} below "
+                         f"the f32 maximum > {TOKEN_MARGIN:g}")
     if tick_cell is None:
         raise SmokeError("serve: no mid-decode tick was captured")
     summary = {"requests": len(done), "waves": waves,
                "ticks": eng.stats["ticks"], "logit_err": worst,
                "logit_tol": GEMMA_LOGIT_TOL,
-               "argmax_agree": f"{agree}/{rows_checked}",
+               "first_token_agree": f"{first_agree}/{N_REQUESTS}",
+               "token_agree": f"{tok_agree}/{len(gaps)}",
+               "token_gap": worst_gap, "token_margin": TOKEN_MARGIN,
                "params": n_params, **times}
     return summary, tick_cell
 
@@ -281,8 +317,7 @@ def gemma_serve(cfg, clock: CompileClock, *, seed: int) -> tuple:
 # phase 3: the decode tick under graph-level noise
 # ---------------------------------------------------------------------------
 
-def decode_probe(tick_cell, clock: CompileClock, *, name: str,
-                 ks=DECODE_KS, reps: int = 3) -> dict:
+def decode_probe(tick_cell, clock: CompileClock, *, name: str) -> dict:
     from repro.core import Controller, step_region
     from repro.core.noise import NoiseScale, make_modes
 
@@ -290,11 +325,11 @@ def decode_probe(tick_cell, clock: CompileClock, *, name: str,
     tk_fn, tk_args = tick_cell
     region = step_region(name, tk_fn, tk_args,
                          {m: registry[m] for m in DECODE_MODES})
-    ctl = Controller(reps=reps)
+    ctl = Controller(reps=PROBE_REPS)
     out: dict = {}
     with clock.phase("decode_probe", out):
         for mode in DECODE_MODES:
-            r = ctl.run_mode(region, mode, ks=ks)
+            r = ctl.run_mode(region, mode, ks=DECODE_KS)
             inj = r.injection
             if inj is None or not inj.ok():
                 raise SmokeError(f"{name}/{mode}: payload not verified "
@@ -334,9 +369,7 @@ def _ici_numpy(mode: str, v, k: int, size: int):
                            for b, s in zip(blocks, shards)])
 
 
-def ici_phase(clock: CompileClock, *, n_chips: int, reps: int = 3,
-              d_model: int = 1024, d_ff: int = 4096, batch: int = 64,
-              devices=None) -> dict:
+def ici_phase(clock: CompileClock, *, n_chips: int) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -347,7 +380,8 @@ def ici_phase(clock: CompileClock, *, n_chips: int, reps: int = 3,
     from repro.core.injector import inject
     from repro.core.noise import NoiseScale, make_modes
 
-    devs = (devices if devices is not None else jax.devices())[:n_chips]
+    d_model, d_ff, batch = ICI_MLP
+    devs = jax.devices()[:n_chips]
     mesh = compat.make_mesh((1, n_chips), ("data", "model"), devices=devs)
     rng = np.random.default_rng(0)
     x = jax.device_put(rng.standard_normal((batch, d_model), np.float32),
@@ -378,7 +412,7 @@ def ici_phase(clock: CompileClock, *, n_chips: int, reps: int = 3,
                           ici_axis="model")
     region = step_region(f"tp_mlp_d{d_model}_f{d_ff}_x{n_chips}", tp_step,
                          (x, w1, w2), {m: registry[m] for m in ICI_MODES})
-    ctl = Controller(reps=reps)
+    ctl = Controller(reps=PROBE_REPS)
     out: dict = {"tp_step_err": step_err}
     with clock.phase("ici", out):
         for mode in ICI_MODES:
@@ -397,8 +431,9 @@ def ici_phase(clock: CompileClock, *, n_chips: int, reps: int = 3,
             r = ctl.run_mode(region, mode, ks=ICI_KS)
             inj = r.injection
             ok = n_ops > 0 and err < 1e-5 and inj is not None and inj.ok()
-            print(f"  {mode}: {n_ops} {ICI_OPCODE[mode]} op(s) in the "
-                  f"compiled HLO at k={k}; buffer vs NumPy {err:.3g}; "
+            print(f"  {mode}: {n_ops} {ICI_OPCODE[mode]} instruction(s) "
+                  f"in the compiled HLO at k={k} (async start/done clones "
+                  f"included); buffer vs NumPy {err:.3g}; "
                   f"payload {inj.payload if inj else None}/{k if inj else '-'}"
                   f"; Abs^raw {r.fit.k1:.1f}; t(k)="
                   + ", ".join(f"{t * 1e3:.3f} ms" for t in r.curve.ts),

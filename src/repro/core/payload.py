@@ -15,6 +15,7 @@ for that (code, mode, k) are biased; the controller re-emits with more chains.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Optional
 
 from repro.core.noise import NOISE_SCOPE
@@ -63,13 +64,25 @@ class InjectionReport:
         tot = self.payload + self.overhead
         return self.overhead / tot if tot else 0.0
 
-    def ok(self, min_survival: float = 0.9) -> bool:
-        """The payload verified: the k patterns survived and, for a Pallas
-        kernel, its main output matched the reference. Overhead is
-        reported, not gated: an ICI pattern carries one local op per
-        collective by construction."""
-        return (self.survival_fraction >= min_survival
+    def ok(self, min_survival: float = 0.9, max_overhead: float = 0.5
+           ) -> bool:
+        """The payload verified: the k patterns survived, overhead stayed
+        bounded and, for a Pallas kernel, its main output matched the
+        reference. An ICI pattern is one collective, so more collectives
+        than patterns fail; it is exempt from the overhead bound, since each
+        collective carries its local op (the all-reduce mean's divide, the
+        all-gather's row slice) by construction. Other patterns may count
+        several payload ops (a VMEM pattern loads and adds)."""
+        if self.target == "ici":
+            fits = self.payload <= self.expected
+        else:
+            fits = self.overhead_fraction <= max_overhead
+        return (min_survival <= self.survival_fraction and fits
                 and (self.ref_tol is None or self.ref_err <= self.ref_tol))
+
+
+_ASYNC_MARK_RE = re.compile(
+    r'custom_call_target="AsyncCollective(Start|Done)"')
 
 
 def _is_noise(ins: Instr) -> bool:
@@ -109,8 +122,14 @@ def analyze_injection(compiled_text: str, *, mode: str, target: str,
     payload = overhead = 0
     payload_dyn = 0
     noisy_comps: set[str] = set()
+    # the TPU compiler splits an async collective into start, overlapped and
+    # done fusions; the start and done fusions hold clones of the op for
+    # bookkeeping, marked by their AsyncCollectiveStart/Done custom call
+    clones = {cname for cname, instrs in comps.items()
+              if any(ins.opcode == "custom-call"
+                     and _ASYNC_MARK_RE.search(ins.line) for ins in instrs)}
     for cname, instrs in comps.items():
-        if cname in combiners:
+        if cname in combiners or cname in clones:
             continue
         for ins in instrs:
             if not (_is_noise(ins) or ins.name in combined):

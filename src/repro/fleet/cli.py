@@ -594,10 +594,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="how many workers the grid splits across")
     pp.add_argument("--workers", type=int, default=1,
                     help="threads per shard")
-    pp.add_argument("--backend", default="auto",
-                    choices=("auto", "interpret", "pallas"),
-                    help="Pallas backend: auto/pallas compile for the TPU "
-                         "and refuse to run without one; interpret runs the "
+    pp.add_argument("--backend", default="pallas",
+                    choices=("pallas", "interpret"),
+                    help="Pallas backend: pallas compiles for the TPU "
+                         "and refuses to run without one; interpret runs the "
                          "Pallas interpreter")
     pp.add_argument("--no-compile-once", action="store_true",
                     help="force the trace-per-k fallback sweep path")

@@ -129,7 +129,7 @@ class TargetSpec:
         return {k: v for k, v in self.params.items()
                 if k not in ("kernel", "sizes", "qs")}
 
-    def resolve(self, backend: str = "auto") -> list:
+    def resolve(self, backend: str = "pallas") -> list:
         """Build this spec's RegionTargets (in the calling process)."""
         if self.kind == "pallas":
             from repro.kernels.region import pallas_family
@@ -227,7 +227,7 @@ class SweepPlan:
     shards: int = 1
     workers: int = 1
     compile_once: bool = True
-    backend: str = "auto"
+    backend: str = "pallas"
     launcher: Optional[dict] = None
     retry: Optional[dict] = None
     store_format: Optional[str] = None
@@ -245,6 +245,9 @@ class SweepPlan:
             raise PlanError("plan has no targets")
         if self.shards < 1 or self.workers < 1 or self.reps < 1:
             raise PlanError("shards, workers and reps must be >= 1")
+        if self.backend not in ("pallas", "interpret"):
+            raise PlanError(f"backend {self.backend!r} unknown; one of "
+                            "['pallas', 'interpret']")
         for spec in self.targets:
             spec.validate()
         self._validate_distribution()
@@ -353,7 +356,7 @@ class SweepPlan:
                    reps=int(d.get("reps", 2)), shards=int(d.get("shards", 1)),
                    workers=int(d.get("workers", 1)),
                    compile_once=bool(d.get("compile_once", True)),
-                   backend=d.get("backend", "auto"),
+                   backend=d.get("backend", "pallas"),
                    launcher=d.get("launcher"), retry=d.get("retry"),
                    store_format=d.get("store_format"),
                    quality=d.get("quality"))

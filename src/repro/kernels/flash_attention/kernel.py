@@ -61,6 +61,7 @@ def _fa_body(q_ref, k_ref, v_ref, noise_ref, o_ref, nacc_ref,
         k = k_ref[0].astype(jnp.float32)                  # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)  # (bq,bk)
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -78,7 +79,8 @@ def _fa_body(q_ref, k_ref, v_ref, noise_ref, o_ref, nacc_ref,
         corr = jnp.exp(m_prev - m_new)                    # (bq,1)
         l_new = corr * l_ref[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p, v, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 

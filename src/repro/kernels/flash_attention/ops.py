@@ -18,7 +18,7 @@ from repro.kernels.noisy_matmul.ops import default_noise_operand
 def flash_attention(q, k, v, noise=None, *, causal: bool = True,
                     window: int = 0, bq: int = 128, bk: int = 128,
                     mode: str = "none", k_noise: int = 0,
-                    backend: str = "auto"):
+                    backend: str = "pallas"):
     """Blocked attention. Returns (out, nacc)."""
     if noise is None:
         noise = default_noise_operand(jnp.float32)
@@ -35,7 +35,7 @@ def flash_attention(q, k, v, noise=None, *, causal: bool = True,
                                    "backend"))
 def flash_attention_rt(kq, q, k, v, noise=None, *, causal: bool = True,
                        window: int = 0, bq: int = 128, bk: int = 128,
-                       mode: str = "fp", backend: str = "auto"):
+                       mode: str = "fp", backend: str = "pallas"):
     """Runtime-k blocked attention: ``kq`` is a traced int32 noise quantity
     (compile-once sweeps), pattern-identical to
     ``flash_attention(..., k_noise=kq)`` for kq ≤ noise_slots.K_MAX."""

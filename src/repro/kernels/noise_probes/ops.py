@@ -13,7 +13,7 @@ from repro.kernels.noisy_matmul.ops import default_noise_operand
 
 @partial(jax.jit, static_argnames=("mode", "k_noise", "n_steps", "backend"))
 def run_probe(noise=None, *, mode: str = "fp", k_noise: int = 1,
-              n_steps: int = 128, backend: str = "auto"):
+              n_steps: int = 128, backend: str = "pallas"):
     if noise is None:
         noise = default_noise_operand()
     if backend == "ref":
@@ -24,7 +24,7 @@ def run_probe(noise=None, *, mode: str = "fp", k_noise: int = 1,
 
 @partial(jax.jit, static_argnames=("mode", "n_steps", "backend"))
 def run_probe_rt(k, noise=None, *, mode: str = "fp", n_steps: int = 128,
-                 backend: str = "auto"):
+                 backend: str = "pallas"):
     """Runtime-k calibration probe: ``k`` is a traced int32 operand, so the
     per-pattern-cost sweep reuses one executable per mode."""
     if noise is None:

@@ -41,6 +41,7 @@ def _mm_body(a_ref, b_ref, noise_ref, o_ref, nacc_ref, acc_ref, emit):
     ns.init_noise(nacc_ref, (i == 0) & (j == 0) & (kk == 0))
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
 
     # noise slot: after the FMA, before the writeback

@@ -21,10 +21,10 @@ def default_noise_operand(dtype=jnp.float32):
                                    "backend"))
 def noisy_matmul(a, b, noise=None, *, mode: str = "none", k_noise: int = 0,
                  bm: int = 256, bn: int = 256, bk: int = 256,
-                 backend: str = "auto"):
+                 backend: str = "pallas"):
     """Matmul with optional kernel-level noise.
 
-    backend: "pallas"/"auto" (the TPU; raises without one), "interpret"
+    backend: "pallas" (the TPU; raises without one), "interpret"
     (the Pallas interpreter, any backend), "ref" (oracle).
     Returns (out, nacc); nacc is zeros for mode="none".
     """
@@ -40,7 +40,7 @@ def noisy_matmul(a, b, noise=None, *, mode: str = "none", k_noise: int = 0,
 @partial(jax.jit, static_argnames=("mode", "bm", "bn", "bk", "backend"))
 def noisy_matmul_rt(k, a, b, noise=None, *, mode: str = "fp",
                     bm: int = 256, bn: int = 256, bk: int = 256,
-                    backend: str = "auto"):
+                    backend: str = "pallas"):
     """Runtime-k matmul: ``k`` is a traced int32 operand (compile-once
     sweeps). Pattern-for-pattern identical to ``noisy_matmul(..., k_noise=k)``
     for k ≤ noise_slots.K_MAX."""

@@ -21,8 +21,8 @@ the instruction-granularity Pallas kernels on the same spine:
     oracle — stronger than op counting, since the accumulated value pins
     both that ALL k patterns executed and that none was duplicated.
 
-Backends (``repro.kernels.backend``): "pallas" and "auto" compile the kernel
-with Mosaic and refuse to build a region where JAX finds no TPU;
+Backends (``repro.kernels.backend``): "pallas" compiles the kernel
+with Mosaic and refuses to build a region where JAX finds no TPU;
 "interpret" runs the kernel body in the Pallas interpreter, for tests and
 docs. On the chip the payload check also holds the kernel's main output to
 a float32 reference (``REF_TOL``), and the static audit leaves the region
@@ -88,9 +88,10 @@ def validate_size(kernel: str, n: int) -> None:
 MODE_TARGETS = {"fp": "compute", "mxu": "compute", "vmem": "vmem"}
 
 # max|out - ref| / max|ref| the payload check allows the kernel's main
-# output against its float32 reference: the MXU kernels may take single-pass
-# bf16 products of f32 operands (~2e-3 at n=4096); SPMXV is f32 VPU work
-REF_TOL = {"matmul": 1e-2, "spmxv": 1e-5, "attention": 1e-2}
+# output against its float32 reference. The MXU kernels ask Mosaic for
+# float32 products (Precision.HIGHEST); a single bf16 pass of the same
+# operands reads ~2e-3, so 1e-4 tells the two apart. SPMXV is f32 VPU work
+REF_TOL = {"matmul": 1e-4, "spmxv": 1e-5, "attention": 1e-4}
 
 # the static payload check runs at most this many patterns: its nacc oracle
 # holds only up to float32 summation error, which grows with the adds one
@@ -282,7 +283,7 @@ def _nacc_of(result):
     return result[-1] if isinstance(result, (tuple, list)) else result
 
 
-def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
+def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
                   trace_hook: Optional[Callable[[], None]] = None,
                   **sizes) -> RegionTarget:
     """A RegionTarget over one Pallas kernel, ready for
@@ -324,6 +325,7 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
     def args_for(mode: str, k: int):
         return spec.args
 
+    @functools.cache     # the payload check reuses the sweep's executable
     def build_rt(mode: str):
         _check_mode(mode)
         return _jit(spec.rt_fn(mode))
@@ -338,11 +340,12 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
     def payload_check(mode: str, k: int) -> InjectionReport:
         """Arithmetic-level static payload check: run the static-k build
         once; an exact oracle match (or a nonzero accumulator for modes
-        without a closed-form oracle) proves all k patterns executed. The
-        same run's main output is compared with the float32 reference.
-        Checks at most ``CHECK_K_MAX`` patterns."""
+        without a closed-form oracle) proves all k patterns executed.
+        Checks at most ``CHECK_K_MAX`` patterns. The main output of that
+        run, and of the runtime-k build the sweep timed at ``k`` itself, is
+        compared with the float32 reference; ``ref_err`` is the worse."""
         _check_mode(mode)
-        k = min(k, CHECK_K_MAX)
+        k_swept, k = k, min(k, CHECK_K_MAX)
         result = build(mode, k)(*spec.args)
         nacc = np.asarray(_nacc_of(result), np.float32)
         want = spec.oracle(mode, k)
@@ -354,9 +357,12 @@ def pallas_region(kernel: str, *, backend: str = "auto", name: str = "",
         ref_err = ref_tol = None
         if spec.reference is not None:
             ref = reference()
-            out = np.asarray(result[0], np.float64).reshape(ref.shape)
-            ref_err = float(np.max(np.abs(out - ref))
-                            / max(float(np.max(np.abs(ref))), 1e-30))
+            swept = build_rt(mode)(jnp.int32(k_swept), *spec.args)
+            ref_err = max(
+                float(np.max(np.abs(np.asarray(out, np.float64)
+                                    .reshape(ref.shape) - ref))
+                      / max(float(np.max(np.abs(ref))), 1e-30))
+                for out in (result[0], swept[0]))
             ref_tol = REF_TOL[kernel]
         return InjectionReport(
             mode=mode, target=MODE_TARGETS[mode], expected=k,
@@ -425,7 +431,7 @@ def family_names(kernel: str, sizes, *, qs=None, **common) -> list[str]:
             for kw in _family_grid(kernel, sizes, qs)]
 
 
-def pallas_family(kernel: str, sizes, *, qs=None, backend: str = "auto",
+def pallas_family(kernel: str, sizes, *, qs=None, backend: str = "pallas",
                   trace_hook: Optional[Callable[[], None]] = None,
                   **common) -> list[RegionTarget]:
     """One RegionTarget per size (× swap probability q for spmxv), sharing

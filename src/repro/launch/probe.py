@@ -136,7 +136,7 @@ def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
                workers: int, compile_once: bool,
                shard: Optional[tuple[int, int]], expect_no_measure: bool,
                header: str, audit: str = "gate",
-               quality: str = "gate", backend: str = "auto") -> None:
+               quality: str = "gate", backend: str = "pallas") -> None:
     """Build a one-target SweepPlan from CLI flags and execute it through
     the fleet worker — the campaign tail (store naming, shard dispatch,
     reporting) lives behind that API now."""
@@ -221,7 +221,7 @@ def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
                  shard: Optional[tuple[int, int]] = None,
                  expect_no_measure: bool = False,
                  audit: str = "gate", quality: str = "gate",
-                 backend: str = "auto") -> None:
+                 backend: str = "pallas") -> None:
     """Run the paper's methodology against a real Pallas kernel (on the
     TPU, or in the interpreter with ``backend="interpret"``). The sweep
     rides the compile-once runtime-k path: ≤2 Pallas executables per
@@ -378,10 +378,10 @@ def build_parser() -> argparse.ArgumentParser:
                     help="probe a Pallas kernel region instead of a model "
                          "step (modes default to the kernel's fp/mxu/vmem "
                          "set)")
-    ap.add_argument("--backend", default="auto",
-                    choices=("auto", "pallas", "interpret"),
-                    help="Pallas backend under --pallas: auto/pallas compile "
-                         "for the TPU and refuse to run without one; "
+    ap.add_argument("--backend", default="pallas",
+                    choices=("pallas", "interpret"),
+                    help="Pallas backend under --pallas: pallas compiles "
+                         "for the TPU and refuses to run without one; "
                          "interpret runs the Pallas interpreter")
     ap.add_argument("--pallas-n", type=int, default=None,
                     help="kernel size knob (rows for matmul/spmxv, seq for "
@@ -456,7 +456,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
             ("--no-compile-once", args.no_compile_once),
             ("--kind", args.kind != "train"), ("--seq", args.seq != 128),
             ("--batch", args.batch != 4),
-            ("--backend", args.backend != "auto")) if given]
+            ("--backend", args.backend != "pallas")) if given]
         if overridden:
             raise SystemExit("--plan carries its own targets, modes and "
                              "settings; drop the conflicting flag(s): "

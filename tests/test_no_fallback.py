@@ -15,27 +15,40 @@ from repro.core.noise import NoiseScale, make_modes
 from repro.core.payload import InjectionReport, analyze_injection
 from repro.kernels.backend import NoChipError
 from repro.kernels.noisy_matmul.ops import noisy_matmul
-from repro.kernels.region import (CHECK_K_MAX, oracle_rtol, pallas_family,
-                                  pallas_region)
+from repro.kernels.region import (CHECK_K_MAX, REF_TOL, oracle_rtol,
+                                  pallas_family, pallas_region)
 
 
 # ---------------------------------------------------------------- backends
 
-@pytest.mark.parametrize("backend", ["auto", "pallas"])
+@pytest.mark.parametrize("backend", [None, "pallas"], ids=["default",
+                                                              "pallas"])
 def test_chip_backend_without_a_chip_raises(backend):
     assert jax.default_backend() != "tpu"
+    kw = {} if backend is None else {"backend": backend}
     with pytest.raises(NoChipError, match="finds none"):
-        pallas_region("matmul", backend=backend, n=128)
+        pallas_region("matmul", n=128, **kw)
     with pytest.raises(NoChipError):
-        pallas_family("probe", [8], backend=backend)
+        pallas_family("probe", [8], **kw)
     a = jnp.ones((128, 128), jnp.float32)
     with pytest.raises(NoChipError):
-        noisy_matmul(a, a, bm=128, bn=128, bk=128, backend=backend)
+        noisy_matmul(a, a, bm=128, bn=128, bk=128, **kw)
 
 
-def test_unknown_backend_is_refused():
+@pytest.mark.parametrize("backend", ["cuda", "auto"])
+def test_unknown_backend_is_refused(backend):
     with pytest.raises(ValueError, match="unknown pallas backend"):
-        pallas_region("probe", backend="cuda", n_steps=8)
+        pallas_region("probe", backend=backend, n_steps=8)
+
+
+def test_plan_refuses_an_unknown_backend(tmp_path):
+    from repro.fleet.plan import PlanError, SweepPlan, TargetSpec
+
+    with pytest.raises(PlanError, match="backend 'auto' unknown"):
+        SweepPlan(name="p", store=str(tmp_path / "s.jsonl"),
+                  targets=[TargetSpec("pallas", ("fp",),
+                                      {"kernel": "probe", "sizes": [8]})],
+                  backend="auto").validate()
 
 
 # ---------------------------------------------------------- payload checks
@@ -93,6 +106,39 @@ def test_pallas_payload_check_holds_main_output_to_reference():
     assert rep.ok()
 
 
+def test_pallas_payload_check_also_runs_the_swept_runtime_k_build():
+    traces = {"n": 0}
+    region = pallas_region(
+        "matmul", backend="interpret", n=128,
+        trace_hook=lambda: traces.__setitem__("n", traces["n"] + 1))
+    assert region.payload_check("fp", 40).ok()
+    assert traces["n"] == 2             # the static check and runtime-k
+    region.build_rt("fp")(jnp.int32(3), *region.args_for_rt("fp"))
+    assert traces["n"] == 2             # the sweep's own executable
+
+
+@pytest.mark.parametrize("kernel", ["matmul", "attention"])
+def test_ref_tol_separates_f32_products_from_one_bf16_pass(kernel):
+    """The MXU kernels ask for float32 products; one bf16 pass of the same
+    operands (Mosaic's default precision) must fail ``REF_TOL``."""
+    from repro.kernels.flash_attention.ref import attention_ref
+
+    sizes = {"matmul": {"n": 256},
+             "attention": {"seq": 128, "heads": 2, "head_dim": 64}}[kernel]
+    region = pallas_region(kernel, backend="interpret", **sizes)
+    rep = region.payload_check("fp", 4)
+    assert rep.ref_err < REF_TOL[kernel]
+
+    args = [jnp.asarray(a) for a in region.args_for("fp", 0)[:-1]]
+    one_pass = [a.astype(jnp.bfloat16).astype(jnp.float32) for a in args]
+    with jax.default_matmul_precision("highest"):
+        fn = jnp.dot if kernel == "matmul" else attention_ref
+        ref, ctl = (np.asarray(fn(*xs), np.float64) for xs in (args,
+                                                                one_pass))
+    err = np.max(np.abs(ctl - ref)) / np.max(np.abs(ref))
+    assert err > REF_TOL[kernel]
+
+
 def test_oracle_tolerance_grows_with_adds_but_catches_a_missing_pattern():
     assert oracle_rtol(100) == 1e-4
     # the chip-size matmul: 32768 grid steps at CHECK_K_MAX patterns
@@ -142,6 +188,64 @@ def test_payload_counts_a_noise_collective_combined_with_the_steps():
                             target="ici", expected=2)
     assert (rep.payload, rep.overhead) == (2, 1)
     assert rep.ok()
+
+
+_ASYNC_HLO = """\
+HloModule m, entry_computation_layout={()->f32[16]}
+
+%start (p0: f32[4]) -> (f32[4], f32[16]) {
+  %p0 = f32[4]{0} parameter(0)
+  %all-gather.1 = f32[16]{0} all-gather(%p0), channel_id=1, dimensions={0}, metadata={op_name="jit(f)/noise_pattern/all_gather"}
+  ROOT %custom-call.1 = (f32[4]{0}, f32[16]{0}) custom-call(%all-gather.1), custom_call_target="AsyncCollectiveStart"
+}
+
+%overlapped (p1: f32[4]) -> f32[16] {
+  %p1 = f32[4]{0} parameter(0)
+  ROOT %all-gather.2 = f32[16]{0} all-gather(%p1), channel_id=1, dimensions={0}, metadata={op_name="jit(f)/noise_pattern/all_gather"}
+}
+
+%done (p2: f32[4], p3: f32[16]) -> f32[16] {
+  %p2 = f32[4]{0} parameter(0)
+  %p3 = f32[16]{0} parameter(1)
+  %all-gather.3 = f32[16]{0} all-gather(%p2), channel_id=1, dimensions={0}, metadata={op_name="jit(f)/noise_pattern/all_gather"}
+  ROOT %custom-call.3 = f32[16]{0} custom-call(%p2, %p3, %all-gather.3), custom_call_target="AsyncCollectiveDone"
+}
+
+ENTRY %main (p: f32[4]) -> f32[16] {
+  %p = f32[4]{0} parameter(0)
+  %s = (f32[4]{0}, f32[16]{0}) fusion(%p), kind=kCustom, calls=%start
+  %o = f32[16]{0} fusion(%p), kind=kCustom, calls=%overlapped
+  %d = f32[16]{0} fusion(%p, %o), kind=kCustom, calls=%done
+  %all-gather.4 = f32[16]{0} all-gather(%p), channel_id=1, dimensions={0}, metadata={op_name="jit(f)/noise_pattern/all_gather"}
+  ROOT %add.5 = f32[16]{0} add(%d, %all-gather.4), metadata={op_name="jit(f)/add"}
+}
+"""
+
+
+def test_payload_counts_an_async_collective_once():
+    """The TPU compiler clones an async collective into its start and done
+    fusions; only the overlapped one is the collective."""
+    rep = analyze_injection(_ASYNC_HLO, mode="ici_allgather", target="ici",
+                            expected=2)
+    assert (rep.payload, rep.overhead) == (2, 0)
+    assert rep.ok()
+    assert not analyze_injection(_ASYNC_HLO, mode="ici_allgather",
+                                 target="ici", expected=1).ok()
+
+
+@pytest.mark.parametrize("payload,overhead,target,ok", [
+    (4, 0, "compute", True),
+    (8, 0, "vmem", True),          # a VMEM pattern loads and adds
+    (4, 5, "compute", False),      # the noise came with more overhead
+    (4, 4, "ici", True),           # one local op per collective
+    (5, 4, "ici", False),          # more collectives than patterns
+], ids=["exact", "two-ops-per-pattern", "overhead", "ici-local-op",
+        "ici-extra"])
+def test_payload_report_gates(payload, overhead, target, ok):
+    rep = InjectionReport(mode="m", target=target, expected=4,
+                          payload=payload, overhead=overhead,
+                          payload_dynamic=payload, body_ops=1)
+    assert rep.ok() is ok
 
 
 # ----------------------------------------------------------- fleet launch
