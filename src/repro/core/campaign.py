@@ -125,6 +125,7 @@ from repro.core.quality import (REASON_DRIFT_SPAN, REASON_TIMEOUT,
                                 QualityPolicy, RemeasureBudget,
                                 VERDICT_QUARANTINE, measure_quality)
 from repro.core.payload import InjectionReport
+from repro import spans
 # the tolerant line-streaming reader and the corrupt-store error live in
 # repro.core.segments (shared with the segmented layout); re-exported here
 # because this module is their historical public home
@@ -727,7 +728,8 @@ class Campaign:
         # keep a kernel that hangs on its very first call from parking the
         # shard forever (the timeout is recorded by sweep_mode's caller)
         dl = self._deadline(None)
-        with self._measure_lock:
+        with self._measure_lock, \
+                spans.span("campaign.probe", region=target.name, mode=mode):
             s = self.ctl.probe_sensitivity(target, mode, deadline=dl)
         self._note(measured=2)   # t0 + t(probe_k)
         self.store.append({"kind": "sens", "region": target.name,
@@ -763,7 +765,7 @@ class Campaign:
         outside ``sentinel_tol`` means something changed under the sweep —
         quarantine ONLY the span of fresh points since the last sentinel."""
         fn, a = self._point_fn(target, mode, fn_rt, k0)
-        with self._measure_lock:
+        with self._measure_lock, spans.span("campaign.drift", k=k0):
             t = measure(fn, a, reps=max(self.ctl.reps - 2, 2),
                         deadline=self._deadline(t0))
         self._note(measured=1)
@@ -784,11 +786,16 @@ class Campaign:
 
     def sweep_mode(self, target: RegionTarget, mode: str) -> ModeResult:
         """Measure (or replay) the k-sweep for one (region, mode) pair."""
-        key = (target.name, mode)
-        self._check_meta(target, mode)
-        if self.store.is_done(*key):
-            return self._replay(target, mode)
+        with spans.span("campaign.sweep", region=target.name, mode=mode) as sp:
+            self._check_meta(target, mode)
+            replayed = self.store.is_done(target.name, mode)
+            sp.set_metadata(replayed=replayed)
+            if replayed:
+                return self._replay(target, mode)
+            return self._measure_sweep(target, mode)
 
+    def _measure_sweep(self, target: RegionTarget, mode: str) -> ModeResult:
+        key = (target.name, mode)
         try:
             ks = self.ctl._ks_for(self._sensitivity(target, mode))
         except MeasureTimeout as e:
@@ -820,7 +827,8 @@ class Campaign:
                 self._note(cached=1)
             elif self.quality is None:
                 fn, a = self._point_fn(target, mode, fn_rt, k)
-                with self._measure_lock:
+                with self._measure_lock, \
+                        spans.span("campaign.point", k=k, reps=self.ctl.reps):
                     t = measure(fn, a, reps=self.ctl.reps)
                 self._note(measured=1)
                 n_fresh += 1
@@ -837,7 +845,8 @@ class Campaign:
                     return measure_sample(_fn, _a, reps=n, deadline=_dl)
 
                 try:
-                    with self._measure_lock:
+                    with self._measure_lock, spans.span(
+                            "campaign.point", k=k, reps=self.ctl.reps):
                         sample, verdict, reason = measure_quality(
                             once, reps=self.ctl.reps, policy=self.quality,
                             budget=self.remeasure)
@@ -887,7 +896,7 @@ class Campaign:
         drift = None
         if n_fresh == len(out_ks) and len(out_ts) > 2 and not timed_out:
             fn, a = self._point_fn(target, mode, fn_rt, out_ks[0])
-            with self._measure_lock:
+            with self._measure_lock, spans.span("campaign.drift", k=out_ks[0]):
                 t0_end = measure(fn, a, reps=max(self.ctl.reps - 2, 2),
                                  deadline=self._deadline(out_ts[0]))
             self._note(measured=1)
@@ -956,7 +965,8 @@ class Campaign:
             return target.body_size
         if target.name in self.store.body_sizes:
             return self.store.body_sizes[target.name]
-        body = derive_body_size(target)
+        with spans.span("campaign.body_size", region=target.name):
+            body = derive_body_size(target)
         self.store.append({"kind": "region", "region": target.name,
                            "body_size": body})
         return body

@@ -28,6 +28,7 @@ from repro.core.absorption import (AbsorptionCurve, AbsorptionFit, absorption,
 from repro.core.classifier import HIGH, LOW, BottleneckReport, classify
 from repro.core.loopnoise import LoopNoise, make_loop_modes
 from repro.core import payload as payload_mod
+from repro.spans import span
 
 log = logging.getLogger("repro.controller")
 
@@ -241,16 +242,19 @@ class Controller:
         only for regions with nothing to verify (a plain, unjitted build, or
         a ``payload_check`` that returns None)."""
         k_chk = next((k for k in reversed(list(ks)) if k), 8)
-        if target.payload_check is not None:
-            rep = target.payload_check(mode, k_chk)
-        else:
-            fn = target.build(mode, k_chk)
-            if not hasattr(fn, "lower"):
-                return None
-            txt = fn.lower(*target.args_for(mode, k_chk)).compile().as_text()
-            tgt = target.payload_target.get(mode, _default_target(mode))
-            rep = payload_mod.analyze_injection(txt, mode=mode, target=tgt,
-                                                expected=k_chk)
+        with span("campaign.payload_check", mode=mode, k=k_chk):
+            if target.payload_check is not None:
+                rep = target.payload_check(mode, k_chk)
+            else:
+                fn = target.build(mode, k_chk)
+                if not hasattr(fn, "lower"):
+                    return None
+                txt = fn.lower(*target.args_for(mode, k_chk)) \
+                    .compile().as_text()
+                tgt = target.payload_target.get(mode, _default_target(mode))
+                rep = payload_mod.analyze_injection(txt, mode=mode,
+                                                    target=tgt,
+                                                    expected=k_chk)
         if rep is not None and not rep.ok():
             raise PayloadError(f"{target.name}/{mode} k={k_chk}: payload "
                                f"check failed: {rep}")

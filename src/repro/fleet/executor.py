@@ -45,6 +45,7 @@ from repro.fleet.launchers import (FleetError, Launcher, LocalLauncher,  # noqa:
                                    RetryBudget, ShardOutcome,
                                    resolve_launcher)
 from repro.fleet.plan import SweepPlan
+from repro.spans import span
 
 log = logging.getLogger("repro.fleet")
 
@@ -733,7 +734,8 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
         # fail-fast: a statically-dead pair refuses the fleet BEFORE any
         # shard launches; records land in the canonical store (pre-merge,
         # so the merge streams them through) and back the evidence below
-        audit_fleet_plan(plan, gate=audit)
+        with span("campaign.fleet.audit", pairs=len(grid)):
+            audit_fleet_plan(plan, gate=audit)
 
     incomplete = sorted(_incomplete_shards(plan, grid, heal=resume))
     for i, ss in state.shards.items():
@@ -784,8 +786,10 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
             except OSError:
                 pass
         state.save()
-        outcomes = lch.launch(plan_path, plan, runnable,
-                              attempts=attempts_map)
+        with span("campaign.fleet.launch", round=round_no,
+                  shards=len(runnable)):
+            outcomes = lch.launch(plan_path, plan, runnable,
+                                  attempts=attempts_map)
         still = set(_incomplete_shards(plan, grid, heal=resume))
         for i in runnable:
             ss = state.shards[i]
@@ -829,7 +833,10 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
         # self-source and adopts only never-seen worker segments)
         if store_exists(plan.store):
             sources = [plan.store] + sources
-        mstats = merge_stores(plan.store, sources)
+        with span("campaign.fleet.merge", sources=len(sources)) as sp:
+            mstats = merge_stores(plan.store, sources)
+            sp.set_metadata(records_in=mstats.records_in,
+                            records_out=mstats.records_out)
         state.merge = {"dest": plan.store, "sources": sources,
                        "records_in": mstats.records_in,
                        "records_out": mstats.records_out,
@@ -841,7 +848,9 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
             state.merge["segments_skipped"] = mstats.segments_skipped
         print(f"== merge: {mstats}")
 
-    reports, cstats = _classify(plan, quality)
+    with span("campaign.fleet.classify",
+              regions=len({r for r, _ in grid})):
+        reports, cstats = _classify(plan, quality)
     state.classification = {
         name: {"label": rep.bottleneck.label,
                "confidence": rep.bottleneck.confidence,
@@ -852,11 +861,12 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
     # the ledger records the refused classification (forensics) but the gate
     # refuses to WRITE a report a majority-quarantined fleet cannot back
     _gate_quality(reports, quality)
-    write_report(plan.report_path(), reports)
-    print(f"== classification ({plan.report_path()}):")
-    for name, rep in sorted(reports.items()):
-        print(f"  {name}: {rep.bottleneck}")
-    finish_stats(cstats, expect_no_measure)
+    with span("campaign.fleet.report"):
+        write_report(plan.report_path(), reports)
+        print(f"== classification ({plan.report_path()}):")
+        for name, rep in sorted(reports.items()):
+            print(f"  {name}: {rep.bottleneck}")
+        finish_stats(cstats, expect_no_measure)
     return FleetResult(plan=plan, reports=reports, stats=cstats, state=state,
                        launched=launched)
 

@@ -189,9 +189,12 @@ def _run_worker_inline(plan_path: str, plan: SweepPlan, index: int) -> int:
     """Execute one shard in THIS process (re-loading the plan from disk like
     a real worker would); exceptions become nonzero returncodes."""
     from repro.fleet.executor import run_worker
+    from repro.spans import span
 
     try:
-        run_worker(SweepPlan.load(plan_path), index=index, count=plan.shards)
+        with span("campaign.worker", shard=index):
+            run_worker(SweepPlan.load(plan_path), index=index,
+                       count=plan.shards)
         return 0
     except SystemExit as e:
         return int(bool(e.code))

@@ -175,6 +175,7 @@ def flash_attention_pallas(q, k, v, noise, *, causal: bool = True,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention",
     )(*flat, noise)
     return out.reshape(B, H, Sq, hd), nacc
 
@@ -200,5 +201,6 @@ def flash_attention_pallas_rt(kq, q, k, v, noise, *, causal: bool = True,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_attention_rt",
     )(ns.k_operand(kq), *flat, noise)
     return out.reshape(B, H, Sq, hd), nacc

@@ -46,6 +46,7 @@ def probe_pallas(noise, *, mode: str, k_noise: int, n_steps: int,
         out_specs=ns.noise_out_spec(1),
         out_shape=ns.noise_out_shape(),
         interpret=interpret,
+        name="noise_probe",
     )(noise)
 
 
@@ -62,4 +63,5 @@ def probe_pallas_rt(k, noise, *, mode: str, n_steps: int,
         grid_spec=grid_spec,
         out_shape=ns.noise_out_shape(),
         interpret=interpret,
+        name="noise_probe_rt",
     )(ns.k_operand(k), noise)

@@ -103,6 +103,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, noise: jax.Array, *,
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="noisy_matmul",
     )(a, b, noise)
     return out, nacc
 
@@ -138,5 +139,6 @@ def matmul_pallas_rt(k, a: jax.Array, b: jax.Array, noise: jax.Array, *,
             ns.noise_out_shape(),
         ],
         interpret=interpret,
+        name="noisy_matmul_rt",
     )(ns.k_operand(k), a, b, noise)
     return out, nacc

@@ -52,6 +52,7 @@ from repro.kernels.noisy_matmul.ops import default_noise_operand
 from repro.kernels.spmv_ell.kernel import spmv_ell_pallas, spmv_ell_pallas_rt
 from repro.kernels.spmv_ell.ref import (fp_noise_ell_ref, make_band_ell,
                                         vmem_noise_ell_ref)
+from repro.spans import span
 
 # noise modes each kernel supports (spmv has no VMEM noise operand -> no mxu)
 KERNEL_MODES = {
@@ -298,7 +299,8 @@ def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
         raise ValueError(f"unknown pallas kernel {kernel!r}; "
                          f"one of {sorted(_SPECS)}")
     interpret = use_interpreter(backend)
-    spec = _SPECS[kernel](interpret, **sizes)
+    with span("campaign.region", region=name or _NAMERS[kernel](**sizes)):
+        spec = _SPECS[kernel](interpret, **sizes)
     modes = KERNEL_MODES[kernel]
 
     def _jit(fn):
@@ -346,23 +348,27 @@ def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
         compared with the float32 reference; ``ref_err`` is the worse."""
         _check_mode(mode)
         k_swept, k = k, min(k, CHECK_K_MAX)
-        result = build(mode, k)(*spec.args)
-        nacc = np.asarray(_nacc_of(result), np.float32)
-        want = spec.oracle(mode, k)
-        if want is not None:
-            ok = np.allclose(nacc, np.asarray(want, np.float32),
-                             rtol=oracle_rtol(k * spec.n_steps), atol=1e-5)
-        else:
-            ok = bool(np.abs(nacc).sum() > 0) if k else True
+        with span("campaign.payload_check.static_run", k=k):
+            result = build(mode, k)(*spec.args)
+            nacc = np.asarray(_nacc_of(result), np.float32)
+        with span("campaign.payload_check.oracle", mode=mode, k=k):
+            want = spec.oracle(mode, k)
+            if want is not None:
+                ok = np.allclose(nacc, np.asarray(want, np.float32),
+                                 rtol=oracle_rtol(k * spec.n_steps),
+                                 atol=1e-5)
+            else:
+                ok = bool(np.abs(nacc).sum() > 0) if k else True
         ref_err = ref_tol = None
         if spec.reference is not None:
-            ref = reference()
-            swept = build_rt(mode)(jnp.int32(k_swept), *spec.args)
-            ref_err = max(
-                float(np.max(np.abs(np.asarray(out, np.float64)
-                                    .reshape(ref.shape) - ref))
-                      / max(float(np.max(np.abs(ref))), 1e-30))
-                for out in (result[0], swept[0]))
+            with span("campaign.payload_check.reference"):
+                ref = reference()
+                swept = build_rt(mode)(jnp.int32(k_swept), *spec.args)
+                ref_err = max(
+                    float(np.max(np.abs(np.asarray(out, np.float64)
+                                        .reshape(ref.shape) - ref))
+                          / max(float(np.max(np.abs(ref))), 1e-30))
+                    for out in (result[0], swept[0]))
             ref_tol = REF_TOL[kernel]
         return InjectionReport(
             mode=mode, target=MODE_TARGETS[mode], expected=k,

@@ -158,6 +158,7 @@ def spmv_ell_pallas(vals, cols, x, *, br: int = 128, mode: str = "none",
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="spmv_ell",
     )(*operands)
     return y.reshape(R), nacc
 
@@ -180,5 +181,6 @@ def spmv_ell_pallas_rt(k, vals, cols, x, *, br: int = 128, mode: str = "fp",
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="spmv_ell_rt",
     )(ns.k_operand(k), *operands)
     return y.reshape(R), nacc

@@ -7,6 +7,8 @@ The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -22,6 +24,14 @@ REAL_SIZES = {
     "attention": {"batch": 1, "heads": 8, "kv_heads": 1, "seq": 4096,
                   "head_dim": 256},
     "probe": {"n_steps": 64},
+}
+# kernel -> the name each build gives its pallas_call, which becomes the
+# custom call's HLO instruction and so the operation's name in a device trace
+KERNEL_NAMES = {
+    "matmul": {"static": "noisy_matmul", "rt": "noisy_matmul_rt"},
+    "spmxv": {"static": "spmv_ell", "rt": "spmv_ell_rt"},
+    "attention": {"static": "flash_attention", "rt": "flash_attention_rt"},
+    "probe": {"static": "noise_probe", "rt": "noise_probe_rt"},
 }
 CASES = [(kernel, mode, path) for kernel in sorted(KERNEL_MODES)
          for mode in KERNEL_MODES[kernel] for path in ("static", "rt")]
@@ -71,3 +81,6 @@ def test_kernel_compiles_for_v5e(topo, specs, kernel, mode, path):
                   *shapes]
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    name = KERNEL_NAMES[kernel][path]
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(",
+                     compiled.as_text()), name
