@@ -1,5 +1,11 @@
-"""Pure-jnp oracle for ELL SPMV + the ELL matrix generators used by the
-SPMXV case study (band matrix with swap probability q, paper §6)."""
+"""Oracles for ELL SPMV and the ELL matrix generators used by the SPMXV
+case study (band matrix with swap probability q, paper §6).
+
+``spmv_ell_ref`` is a jnp oracle of the kernel's output. The nacc oracles
+``fp_noise_ell_ref`` and ``vmem_noise_ell_ref`` are NumPy host oracles: they
+take ``vals`` off the device once and add in float32, block by block and
+pattern by pattern, so a payload check dispatches no device program per
+block or pattern."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -13,32 +19,35 @@ def spmv_ell_ref(vals, cols, x):
                    axis=1).astype(x.dtype)
 
 
-def fp_noise_ell_ref(vals, k_noise: int, br: int = 128):
+def fp_noise_ell_ref(vals, k_noise: int, br: int = 128) -> np.ndarray:
     """Exact nacc oracle for spmv_ell mode='fp'.
 
     The kernel has no noise operand; block i's addend is its first 8 rows'
     first column broadcast across lanes (noise_slots._fp_c with a src_ref),
     so nacc = k * sum_i broadcast(vals[i*br : i*br+8, 0]).
     """
-    R = vals.shape[0]
+    v = np.asarray(vals, np.float32)
+    R = v.shape[0]
     br = min(br, R)
-    c = sum(vals[i * br:i * br + 8, 0:1].astype(jnp.float32)
-            for i in range(R // br))
-    return k_noise * jnp.broadcast_to(c, (8, 128))
+    c = np.zeros((8, 1), np.float32)
+    for i in range(R // br):
+        c += v[i * br:i * br + 8, 0:1]
+    return np.float32(k_noise) * np.broadcast_to(c, (8, 128))
 
 
-def vmem_noise_ell_ref(vals, k_noise: int, br: int = 128):
+def vmem_noise_ell_ref(vals, k_noise: int, br: int = 128) -> np.ndarray:
     """Exact nacc oracle for spmv_ell mode='vmem': block i re-reads its own
     (8, min(L,128)) row groups at rotating offsets (step index = i)."""
-    R, L = vals.shape
+    v = np.asarray(vals, np.float32)
+    R, L = v.shape
     br = min(br, R)
     w = min(L, 128)
-    acc = jnp.zeros((8, 128), jnp.float32)
+    acc = np.zeros((8, 128), np.float32)
     for i in range(R // br):
-        blk = vals[i * br:(i + 1) * br].astype(jnp.float32)
+        blk = v[i * br:(i + 1) * br, 0:w]
         for j in range(k_noise):
             off = (i * 7 + j * 13) % max(br - 8, 1)
-            acc = acc.at[:, 0:w].add(blk[off:off + 8, 0:w])
+            acc[:, 0:w] += blk[off:off + 8]
     return acc
 
 

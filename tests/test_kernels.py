@@ -212,3 +212,42 @@ def test_spmv_vmem_noise_exact_narrow_block():
                                rtol=1e-5, atol=1e-6)
     assert np.abs(nacc[:, :16]).sum() > 0
     np.testing.assert_array_equal(nacc[:, 16:], 0.0)
+
+
+# Witnesses for the host nacc oracles: the same float32 loops as eager jnp,
+# one device op per block and pattern.
+def _fp_noise_ell_eager(vals, k_noise, br):
+    R = vals.shape[0]
+    br = min(br, R)
+    c = sum(vals[i * br:i * br + 8, 0:1].astype(jnp.float32)
+            for i in range(R // br))
+    return k_noise * jnp.broadcast_to(c, (8, 128))
+
+
+def _vmem_noise_ell_eager(vals, k_noise, br):
+    R, L = vals.shape
+    br = min(br, R)
+    w = min(L, 128)
+    acc = jnp.zeros((8, 128), jnp.float32)
+    for i in range(R // br):
+        blk = vals[i * br:(i + 1) * br].astype(jnp.float32)
+        for j in range(k_noise):
+            off = (i * 7 + j * 13) % max(br - 8, 1)
+            acc = acc.at[:, 0:w].add(blk[off:off + 8, 0:w])
+    return acc
+
+
+@pytest.mark.parametrize("mode", ["fp", "vmem"])
+@pytest.mark.parametrize("n,k,q,br", [(512, 0, 0.0, 128), (512, 3, 0.25, 128),
+                                      (256, 5, 1.0, 512),   # br > rows
+                                      (32768, 16, 1.0, 128)])
+def test_spmv_noise_oracles_match_eager_bitwise(mode, n, k, q, br):
+    """The NumPy nacc oracles equal the eager jnp loops bit for bit, up to
+    the benchmark's 2^15 rows at k = 16, and return host float32 (8, 128)."""
+    vals, _ = make_band_ell(n, 16, q, seed=n + k)
+    host, eager = {"fp": (fp_noise_ell_ref, _fp_noise_ell_eager),
+                   "vmem": (vmem_noise_ell_ref, _vmem_noise_ell_eager)}[mode]
+    got = host(vals, k, br)
+    assert isinstance(got, np.ndarray)
+    assert got.shape == (8, 128) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, np.asarray(eager(vals, k, br)))

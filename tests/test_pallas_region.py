@@ -7,8 +7,10 @@ import numpy as np
 import pytest
 
 from repro.core import Campaign, Controller
-from repro.kernels.region import (KERNEL_MODES, family_names, pallas_family,
-                                  pallas_region, validate_size)
+from repro.kernels.region import (KERNEL_MODES, family_names, oracle_rtol,
+                                  pallas_family, pallas_region, validate_size)
+from repro.kernels.spmv_ell.ref import (fp_noise_ell_ref, make_band_ell,
+                                        vmem_noise_ell_ref)
 
 
 def _counting_region(kernel, **sizes):
@@ -77,6 +79,27 @@ def test_pallas_payload_check_oracle():
         assert rep.expected == rep.payload == 6
         assert rep.overhead == 0 and rep.survival_fraction == 1.0
         assert rep.ok()
+
+
+@pytest.mark.parametrize("mode", sorted(KERNEL_MODES["spmxv"]))
+def test_pallas_payload_check_oracle_spmxv(mode):
+    """The SPMXV payload check holds its kernel to the host nacc oracle."""
+    region, _ = _counting_region("spmxv", n=256)
+    rep = region.payload_check(mode, 6)
+    assert rep.expected == rep.payload == 6
+    assert rep.ok()
+
+
+@pytest.mark.parametrize("mode", sorted(KERNEL_MODES["spmxv"]))
+@pytest.mark.parametrize("k_off", [-1, 1])
+def test_spmxv_oracle_separates_a_missing_pattern(mode, k_off):
+    """At the benchmark's 2^15 rows and k = 16 the payload check's
+    tolerance still tells the k = 16 oracle from one a pattern off."""
+    vals, _ = make_band_ell(2 ** 15, 16, 1.0, seed=0)
+    oracle = {"fp": fp_noise_ell_ref, "vmem": vmem_noise_ell_ref}[mode]
+    want = oracle(vals, 16, 128)
+    assert not np.allclose(oracle(vals, 16 + k_off, 128), want,
+                           rtol=oracle_rtol(16 * 256), atol=1e-5)
 
 
 def test_pallas_region_rejects_unknown_mode():
