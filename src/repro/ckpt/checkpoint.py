@@ -52,6 +52,36 @@ def _from_storable(a: np.ndarray, dtype_str: str) -> np.ndarray:
     return a.astype(target)
 
 
+def leaf_name(path) -> str:
+    """A key path's file stem: ``('layers', 'attn', 'wq')`` ->
+    ``"layers.attn.wq"``."""
+    return ".".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path)
+
+
+def read_leaves(directory: str, like: Any) -> Any:
+    """A tree shaped like ``like`` (arrays or ShapeDtypeStructs), each leaf
+    memory-mapped from ``<directory>/<leaf_name>.npy``.
+
+    A file holds its leaf's shape in the leaf's dtype or, for a type NumPy
+    cannot store (bfloat16, the float8s), in unsigned ints of the same
+    width, read back bit for bit; any other shape or dtype raises
+    ValueError — nothing is cast."""
+    def one(path, want):
+        name = leaf_name(path)
+        a = np.load(os.path.join(directory, f"{name}.npy"), mmap_mode="r")
+        dt = np.dtype(want.dtype)
+        if a.dtype != dt and (str(dt) in _NATIVE
+                              or a.dtype != _UINT_OF[dt.itemsize]):
+            raise ValueError(f"{name}: file holds {a.dtype}, want {dt}")
+        if a.shape != tuple(want.shape):
+            raise ValueError(f"{name}: file holds {a.shape}, want "
+                             f"{tuple(want.shape)}")
+        return a.view(dt)
+
+    return jax.tree_util.tree_map_with_path(one, like)
+
+
 class CheckpointManager:
     def __init__(self, directory: str, *, keep: int = 3):
         self.dir = directory

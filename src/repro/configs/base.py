@@ -25,6 +25,7 @@ class ModelConfig:
     act: str = "swiglu"          # swiglu | geglu
     norm_eps: float = 1e-5
     rope_theta: float = 10_000.0
+    rope_scaling: float = 1.0    # linear RoPE scaling: positions / factor
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
 

@@ -14,6 +14,7 @@ tests assert bit-identical outputs for every k.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -71,7 +72,15 @@ def step_region(name: str, step_fn: Callable, args: tuple,
                 registry: dict[str, NoiseMode], *, body_size: int = 0,
                 rng: Optional[jax.Array] = None):
     """Adapt a jitted step + graph-level noise registry into a RegionTarget
-    (with both the trace-per-k and the compile-once build paths)."""
+    (with both the trace-per-k and the compile-once build paths).
+
+    The region's payload check runs what it checks: it counts the surviving
+    patterns of the static build at ``min(k, CHECK_K_MAX)``, runs that
+    build once, and holds its outputs, and those of the runtime-k build at
+    the swept k, to the outputs at k=0 (the runtime-k build's where the
+    mode has one, so the check compiles nothing the sweep did not, else
+    the clean step's): injection must leave them bit-identical
+    (``ref_err`` the worst relative difference, ``ref_tol`` 0)."""
     from repro.core.controller import RegionTarget   # cycle: controller->here
 
     rng = jax.random.PRNGKey(0) if rng is None else rng
@@ -87,6 +96,7 @@ def step_region(name: str, step_fn: Callable, args: tuple,
             return args
         return (states[mode], *args)
 
+    @functools.cache     # the payload check reuses the sweep's executable
     def build_rt(mode: str):
         if registry[mode].apply_rt is None:
             return None
@@ -95,12 +105,46 @@ def step_region(name: str, step_fn: Callable, args: tuple,
     def args_for_rt(mode: str):
         return (states[mode], *args)
 
+    def payload_check(mode: str, k: int) -> payload_mod.InjectionReport:
+        from repro.kernels.region import CHECK_K_MAX
+        from repro.spans import span
+
+        k_swept, k = k, min(k, CHECK_K_MAX)
+        with span("campaign.payload_check.static_run", k=k):
+            static = build(mode, k).lower(*args_for(mode, k)).compile()
+            out = static(*args_for(mode, k))[0]
+            rep = payload_mod.analyze_injection(
+                static.as_text(), mode=mode, target=registry[mode].target,
+                expected=k)
+        with span("campaign.payload_check.reference"):
+            rt = build_rt(mode)
+            if rt is None:
+                clean, got = build(mode, 0)(*args_for(mode, 0)), [out]
+            else:
+                clean = rt(jnp.int32(0), *args_for_rt(mode))[0]
+                got = [out, rt(jnp.int32(k_swept), *args_for_rt(mode))[0]]
+            err = max(outputs_err(g, clean) for g in got)
+        return dataclasses.replace(rep, ref_err=err, ref_tol=0.0)
+
     return RegionTarget(name=name, build=build, args_for=args_for,
                         body_size=body_size,
                         payload_target={m: registry[m].target
                                         for m in registry},
                         build_rt=build_rt, args_for_rt=args_for_rt,
+                        payload_check=payload_check,
                         audit_hint={"scoped": True, "in_loop": False})
+
+
+def outputs_err(got, want) -> float:
+    """The worst max|got - want| / max|want| over the leaves of two output
+    trees, reduced on the device (only the scalars reach the host)."""
+    worst = 0.0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        g, w = jnp.asarray(g, jnp.float32), jnp.asarray(w, jnp.float32)
+        diff = float(jnp.max(jnp.abs(g - w)))
+        worst = max(worst, diff / max(float(jnp.max(jnp.abs(w))), 1e-30))
+    return worst
 
 
 @dataclasses.dataclass

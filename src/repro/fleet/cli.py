@@ -148,9 +148,19 @@ def _build_plan(args) -> "object":
         from repro.launch.probe import DEFAULT_GRAPH_MODES
         modes = (_csv(args.modes, str) if args.modes
                  else list(DEFAULT_GRAPH_MODES))
-        spec = TargetSpec("serve", tuple(modes),
-                          {"arch": args.arch, "slots": args.batch,
-                           "prompt": args.seq, "max_new": args.max_new})
+        if args.layers is None:
+            params = {"arch": args.arch, "slots": args.batch,
+                      "prompt": args.seq, "max_new": args.max_new}
+        else:
+            params = {"arch": args.arch, "layers": args.layers,
+                      "slots": args.batch, "max_seq": args.max_seq,
+                      "page_size": args.page_size,
+                      "prompt_lens": (_csv(args.prompt_lens, int)
+                                      if args.prompt_lens else [args.seq]),
+                      "max_new": args.max_new,
+                      "regions": _csv(args.regions, str),
+                      "seed": args.seed}
+        spec = TargetSpec("serve", tuple(modes), params)
         default_name = f"fleet_{args.arch}_serve"
     else:
         from repro.launch.probe import DEFAULT_GRAPH_MODES
@@ -580,6 +590,21 @@ def build_parser() -> argparse.ArgumentParser:
                          "is the prompt length, --batch the slot count)")
     pp.add_argument("--max-new", type=int, default=8,
                     help="decode budget per request of a --serve target")
+    pp.add_argument("--layers", type=int, default=None,
+                    help="--serve at the published config, cut to this "
+                         "many layers (default: the smoke config)")
+    pp.add_argument("--prompt-lens", default=None,
+                    help="with --layers: comma list of the admission "
+                         "wave's prompt lengths (default: --seq)")
+    pp.add_argument("--max-seq", type=int, default=4096,
+                    help="with --layers: positions a slot's pages hold")
+    pp.add_argument("--page-size", type=int, default=16,
+                    help="with --layers: positions a page holds")
+    pp.add_argument("--regions", default="prefill,decode",
+                    help="with --layers: comma list of the regions to "
+                         "probe (prefill, decode)")
+    pp.add_argument("--seed", type=int, default=0,
+                    help="with --layers: seed of the weights and prompts")
     pp.add_argument("--kind", default="train", choices=("train", "decode"),
                     help="model-step flavour to probe")
     pp.add_argument("--seq", type=int, default=128,

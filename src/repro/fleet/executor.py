@@ -705,6 +705,10 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
     digest, when state exists and neither flag was given, when shards still
     owe measurements after the last allowed attempt round, or when a shard
     has exhausted its lifetime ``per_shard_cap``.
+
+    What the plan's targets hold on the device is freed when the campaign
+    has reported (``SweepPlan.release``), so campaigns run back to back do
+    not hold two model engines at once.
     """
     _check_audit_choice(audit)
     _check_quality_choice(quality)
@@ -867,6 +871,7 @@ def run_fleet(plan_path: str, *, resume: bool = False, fresh: bool = False,
         for name, rep in sorted(reports.items()):
             print(f"  {name}: {rep.bottleneck}")
         finish_stats(cstats, expect_no_measure)
+    plan.release()
     return FleetResult(plan=plan, reports=reports, stats=cstats, state=state,
                        launched=launched)
 

@@ -54,10 +54,14 @@ class TargetSpec:
         ``pallas_family`` to one RegionTarget per size/q;
       * "step"   — params {arch[, kind, seq, batch]}; resolves via
         ``repro.launch.probe.build_step_region`` to one model-step region;
-      * "serve"  — params {arch[, slots, prompt, max_new, page_size]};
-        resolves via ``repro.serve.load.build_serve_regions`` to TWO regions
-        of one paged serving workload: the engine's batched prefill and its
-        decode tick, probed (and classified) separately;
+      * "serve"  — params {arch[, slots, prompt, max_new, page_size]}
+        (the smoke config), or {arch, layers[, slots, max_seq, page_size,
+        prompt_lens, max_new, regions, seed, weights]} (the published config
+        cut to ``layers`` layers, its weights read from the directory
+        ``weights`` or drawn from ``seed``); resolves via
+        ``repro.serve.load.build_serve_target`` to regions of one paged
+        serving workload: the engine's batched prefill and its decode tick,
+        probed (and classified) separately;
       * "calibrate" — params {[n, chunk]}; resolves via
         ``repro.core.calibration.calibrate_targets`` to the four
         known-regime threshold-calibration regions (synthetic-clock only).
@@ -101,11 +105,11 @@ class TargetSpec:
             if bad:
                 raise PlanError(f"unknown graph-level mode(s) {bad}")
             if self.kind == "serve":
-                for key in ("slots", "prompt", "max_new", "page_size"):
-                    v = self.params.get(key)
-                    if v is not None and (not isinstance(v, int) or v < 1):
-                        raise PlanError(f"serve target {key}={v!r}: want a "
-                                        "positive int")
+                from repro.serve.load import check_serve_params
+                try:
+                    check_serve_params(self.params)
+                except ValueError as e:
+                    raise PlanError(str(e)) from e
         elif self.kind == "calibrate":
             from repro.core.calibration import CALIB_MODES
             bad = [m for m in self.modes if m not in CALIB_MODES]
@@ -142,12 +146,8 @@ class TargetSpec:
             return calibrate_targets(n=int(p.get("n", 4096)),
                                      chunk=int(p.get("chunk", 512)))
         if self.kind == "serve":
-            from repro.serve.load import build_serve_regions
-            return build_serve_regions(
-                p["arch"], list(self.modes), slots=int(p.get("slots", 4)),
-                prompt=int(p.get("prompt", 32)),
-                max_new=int(p.get("max_new", 8)),
-                page_size=int(p.get("page_size", 16)))
+            from repro.serve.load import build_serve_target
+            return build_serve_target(p, self.modes)
         from repro.launch.probe import build_step_region
         return [build_step_region(p["arch"], p.get("kind", "train"),
                                   list(self.modes), seq=int(p.get("seq", 128)),
@@ -167,15 +167,18 @@ class TargetSpec:
             from repro.core.calibration import REGIME_NAMES
             return list(REGIME_NAMES)
         if self.kind == "serve":
-            from repro.serve.load import serve_region_names
-            return serve_region_names(p["arch"],
-                                      slots=int(p.get("slots", 4)),
-                                      prompt=int(p.get("prompt", 32)),
-                                      max_new=int(p.get("max_new", 8)),
-                                      page_size=int(p.get("page_size", 16)))
+            from repro.serve.load import serve_target_names
+            return serve_target_names(p)
         from repro.configs import get_smoke_config   # a dataclass, no jax
         return [f"{get_smoke_config(p['arch']).name}_{p.get('kind', 'train')}"
                 f"_s{int(p.get('seq', 128))}_b{int(p.get('batch', 4))}"]
+
+    def release(self) -> None:
+        """Free what ``resolve`` holds on the device beyond the regions
+        themselves: a published serve target's engine."""
+        if self.kind == "serve" and "layers" in self.params:
+            from repro.serve.load import release_serve_engines
+            release_serve_engines()
 
     def to_dict(self) -> dict:
         """The JSON-able form embedded in a plan's ``targets`` list."""
@@ -377,6 +380,12 @@ class SweepPlan:
             self._resolved = [(spec, spec.resolve(self.backend))
                               for spec in self.targets]
         return self._resolved
+
+    def release(self) -> None:
+        """Free every target's device state (``TargetSpec.release``); the
+        fleet calls it when a campaign ends."""
+        for spec in self.targets:
+            spec.release()
 
     def pairs(self) -> list[tuple[object, str]]:
         """The full (RegionTarget, mode) grid in canonical order — the exact

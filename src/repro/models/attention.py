@@ -58,7 +58,8 @@ def _project_qkv(p, cfg, x, positions):
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cd))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cd))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].astype(cd))
-    cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_angles(positions, cfg.head_dim, cfg.rope_theta,
+                           cfg.rope_scaling)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     q = constrain(q.transpose(0, 2, 1, 3), "batch", "heads", "seq", None)

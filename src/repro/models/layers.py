@@ -47,11 +47,18 @@ def rmsnorm(p, x, eps):
 # Rotary position embeddings
 # ----------------------------------------------------------------------------
 
-def rope_angles(positions, head_dim, theta):
-    """positions: int array (...,) -> (cos, sin) of shape (..., head_dim//2), f32."""
+def rope_angles(positions, head_dim, theta, scaling=1.0):
+    """positions: int array (...,) -> (cos, sin) of shape (..., head_dim//2), f32.
+
+    ``scaling`` > 1 is linear RoPE scaling (position interpolation): every
+    position is divided by the factor. At 1 the angles are computed exactly
+    as without it."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = positions.astype(jnp.float32)[..., None] * freqs
+    pos = positions.astype(jnp.float32)
+    if scaling != 1.0:
+        pos = pos / scaling
+    ang = pos[..., None] * freqs
     return jnp.cos(ang), jnp.sin(ang)
 
 

@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.models import transformer as tf
 from repro.models.model import ModelApi
+from repro.spans import span
 
 _PAGED_FAMILIES = ("dense", "moe", "vlm")
 
@@ -155,6 +156,13 @@ class ServeEngine:
             n *= 2
         return min(n, self.max_seq)
 
+    def _admit_pages(self, sp: int, spad: int) -> int:
+        """Pages a ``sp``-token prompt takes at admission in a wave padded
+        to ``spad``: those the padded prefill writes, and the page of
+        position ``sp``, which the first tick writes — one more than the
+        bucket's when the prompt fills it exactly."""
+        return max(spad // self.page_size, sp // self.page_size + 1)
+
     def _set_active(self, slot: int, value: bool) -> None:
         self.active[slot] = value
         self._active_dev = jnp.asarray(self.active)
@@ -196,7 +204,8 @@ class ServeEngine:
         while free_slots and self.queue:
             cand = [r for _, r in wave] + [self.queue[0]]
             spad = self._bucket(max(len(r.prompt) for r in cand))
-            if (spad // self.page_size) * len(cand) > len(self._free):
+            need = sum(self._admit_pages(len(r.prompt), spad) for r in cand)
+            if need > len(self._free):
                 break
             wave.append((free_slots.pop(0), self.queue.popleft()))
         if not wave:
@@ -211,21 +220,23 @@ class ServeEngine:
         for slot, req in wave:
             sp = len(req.prompt)
             toks[slot, :sp] = req.prompt
-            pages = [self._free.pop() for _ in range(npp)]
+            pages = [self._free.pop()
+                     for _ in range(self._admit_pages(sp, spad))]
             self._slot_pages[slot] = pages
             self._table_np[slot, :] = self._trash
-            self._table_np[slot, :npp] = pages
-            rows[slot] = pages
+            self._table_np[slot, :len(pages)] = pages
+            rows[slot] = pages[:npp]
             lens[slot] = sp
             adm[slot] = True
         self.page_table = jnp.asarray(self._table_np)
 
         wave_args = tuple(jnp.asarray(a) for a in (toks, rows, lens, adm))
         self._last_wave = wave_args
-        self.cache, self.pos, self.cur, nxt = self._prefill_jit(
-            self.params, self.cache, *wave_args, self.pos, self.cur,
-            self._next_key())
-        nxt_h = np.asarray(jax.device_get(nxt))
+        with span("serve.admit", requests=len(wave), bucket=spad):
+            self.cache, self.pos, self.cur, nxt = self._prefill_jit(
+                self.params, self.cache, *wave_args, self.pos, self.cur,
+                self._next_key())
+            nxt_h = np.asarray(jax.device_get(nxt))
         for slot, req in wave:
             req.out.append(int(nxt_h[slot]))
             self.slot_req[slot] = req
@@ -245,12 +256,13 @@ class ServeEngine:
                     "n_slots * (max_seq // page_size) pages to rule this "
                     "out")
             return
-        self.cache, self.cur, self.pos, nxt = self._tick_jit(
-            self.params, self.cache, self.cur, self.pos, self._active_dev,
-            self.page_table, self._next_key())
-        # the tick's single host sync: sampled tokens + updated positions
-        nxt_h, pos_h = (np.asarray(a)
-                        for a in jax.device_get((nxt, self.pos)))
+        with span("serve.tick", active=int(self.active.sum())):
+            self.cache, self.cur, self.pos, nxt, _logits = self._tick_jit(
+                self.params, self.cache, self.cur, self.pos,
+                self._active_dev, self.page_table, self._next_key())
+            # the tick's single host sync: sampled tokens + updated positions
+            nxt_h, pos_h = (np.asarray(a)
+                            for a in jax.device_get((nxt, self.pos)))
         self.stats["ticks"] += 1
         self.stats["decode_tokens"] += int(self.active.sum())
         self.stats["occupancy_sum"] += self.pool_occupancy()
@@ -339,9 +351,7 @@ class ServeEngine:
         self.stats["prefill_calls"] += 1
 
     def _step_dense(self) -> None:
-        for slot in range(self.n_slots):
-            if not self.active[slot] and self.queue:
-                self._admit(slot, self.queue.popleft())
+        self.admit()
         if not self.active.any():
             return
         logits, self.cache = self._decode(self.params, self.cache, self.cur,
@@ -384,6 +394,18 @@ class ServeEngine:
             sub, logits / self.temperature, axis=-1).astype(jnp.int32)
 
     # ------------------------------------------------------------------
+    def admit(self) -> bool:
+        """Admit queued requests into free slots without a decode tick
+        (paged: one batched wave); True where any was admitted."""
+        if self.paged:
+            return self._admit_wave()
+        admitted = False
+        for slot in range(self.n_slots):
+            if not self.active[slot] and self.queue:
+                self._admit(slot, self.queue.popleft())
+                admitted = True
+        return admitted
+
     def step(self) -> None:
         """One engine tick: admit into free slots, then one decode step."""
         if self.paged:
@@ -446,7 +468,10 @@ def _make_paged_fns(cfg, temperature: float):
     prefill(params, cache, toks, rows, lens, adm, pos, cur, key)
         -> (cache, pos, cur, next_tokens)
     tick(params, cache, cur, pos, active, table, key)
-        -> (cache, cur, pos, next_tokens)
+        -> (cache, cur, pos, next_tokens, logits)
+
+    The tick's logits are the step's float32 logits, (slots, vocab); the
+    engine samples from them and drops them, a probe compares them.
     """
     def sample(logits, key):
         if temperature <= 0:
@@ -470,6 +495,6 @@ def _make_paged_fns(cfg, temperature: float):
         nxt = sample(logits[:, -1, :], key)
         pos = pos + active.astype(jnp.int32)
         cur = jnp.where(active[:, None], nxt[:, None], cur)
-        return cache, cur, pos, nxt
+        return cache, cur, pos, nxt, logits[:, -1, :].astype(jnp.float32)
 
     return prefill, tick

@@ -84,3 +84,54 @@ def test_kernel_compiles_for_v5e(topo, specs, kernel, mode, path):
     name = KERNEL_NAMES[kernel][path]
     assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(",
                      compiled.as_text()), name
+
+
+# the decode cell's engine: DeepSeek-Coder-33B cut to 8 layers, 4 slots of
+# 4096 positions in pages of 16
+DECODE_TICK = {"layers": 8, "slots": 4, "max_seq": 4096, "page_size": 16}
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.mark.parametrize("mode", ["hbm_stream", "fp_add32"])
+def test_decode_tick_compiles_for_v5e_and_fits(topo, mode):
+    """The runtime-k decode tick at published widths, as the serve kind
+    wraps it, compiles for one v5e chip, and its arguments, outputs and
+    temporaries fit in the chip's memory."""
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.configs import get_config
+    from repro.core.injector import inject_rt
+    from repro.models import transformer as tf
+    from repro.models.model import build
+    from repro.serve.engine import _make_paged_fns
+    from repro.serve.load import _registry
+
+    t = DECODE_TICK
+    cfg = get_config("deepseek-coder-33b").scaled(n_layers=t["layers"])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    pages = t["slots"] * t["max_seq"] // t["page_size"] + 1
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    noise = _registry([mode])[mode]
+    args = on_chip((
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.eval_shape(noise.make_state, jax.random.PRNGKey(0)),
+        jax.eval_shape(build(cfg).init, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: tf.lm_paged_decode_init(
+            None, cfg, pages, t["page_size"])),
+        jax.ShapeDtypeStruct((t["slots"], 1), jnp.int32),
+        jax.ShapeDtypeStruct((t["slots"],), jnp.int32),
+        jax.ShapeDtypeStruct((t["slots"],), jnp.bool_),
+        jax.ShapeDtypeStruct((t["slots"], t["max_seq"] // t["page_size"]),
+                             jnp.int32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    tick = _make_paged_fns(cfg, 0.0)[1]
+    compiled = jax.jit(inject_rt(tick, noise)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 9.4e9 < mem.argument_size_in_bytes
+    assert total < V5E_HBM_BYTES, total
