@@ -206,9 +206,11 @@ class Controller:
     def run_mode(self, target: RegionTarget, mode: str,
                  ks: Optional[Sequence[int]] = None) -> ModeResult:
         """Sweep one mode. Compile-once path: the sensitivity probe and every
-        sweep point reuse ONE runtime-k executable; payload verification adds
-        one static-k executable — at most 2 compilations for the whole sweep
-        (the fallback path compiles one per k, the paper's cost model).
+        sweep point reuse ONE runtime-k executable; payload verification
+        adds one static-k executable for HLO-counted regions (at most 2
+        compilations for the whole sweep) and none for Pallas regions, whose
+        check runs that runtime-k executable (the fallback path compiles one
+        per k, the paper's cost model).
 
         ``ks``: override the sensitivity-chosen quantities (campaign resume).
         """
@@ -234,8 +236,9 @@ class Controller:
         """Static payload check (§2.3) on a trace-per-k executable — the HLO
         of the runtime-k path holds ONE pattern in a loop body, so surviving
         ops must be counted on a static unrolled trace. Regions with a
-        ``payload_check`` override (Pallas kernels) verify against their own
-        oracle instead.
+        ``payload_check`` override verify their own way instead: Pallas
+        kernels hold the runtime-k build's noise accumulator to an exact
+        oracle, so their check needs no static build.
 
         A check that raises, or a report that fails ``ok()``, fails the
         pair: a sweep whose noise did not run measured nothing. Returns None

@@ -5,7 +5,7 @@ the per-pattern cost δ of each noise mode — the constant the analytic
 saturation model needs (core.analytic.pattern_deltas provides spec-sheet
 values; this kernel measures them). On CPU the kernel validates in interpret
 mode: the accumulated value is exactly predictable, proving each pattern
-executed exactly once (static payload check at the arithmetic level).
+executed exactly once (payload check at the arithmetic level).
 
 ``probe_pallas_rt`` is the compile-once twin: the noise quantity is a
 scalar-prefetch int32 operand (runtime-k protocol, see noise_slots) — the

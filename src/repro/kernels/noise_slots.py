@@ -34,11 +34,10 @@ are emitted by a bounded ``lax.fori_loop``:
     j of the static path (same addends, same offsets, same order), so for
     any k ≤ K_MAX the two paths are bitwise identical — asserted per kernel
     and mode in tests/test_kernels.py;
-  * payload verification still happens on a STATIC trace: the compiled
-    runtime-k HLO holds ONE pattern in a loop body, so surviving-op counts
-    (or here, the exact ``nacc`` oracle) are checked on a ``k_noise``-static
-    build — the controller's ≤2-executables-per-sweep budget (runtime-k
-    sweep + static payload check);
+  * payload verification needs no static trace here: the compiled runtime-k
+    HLO holds ONE pattern in a loop body, so surviving ops cannot be counted
+    on it, but the exact ``nacc`` oracle can be checked on its output — one
+    executable per (kernel, mode) sweep, payload check included;
   * the trace-per-k fallback (``Controller(compile_once=False)``) still
     applies when a region cannot thread a traced k — e.g. a hand-rolled
     ``pallas_call`` without the scalar-prefetch operand, or a k that changes

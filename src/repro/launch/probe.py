@@ -27,9 +27,10 @@ workload classify separately (docs/methodology.md §Serving):
 
 Pallas mode probes one of the real kernels (matmul / spmxv / attention /
 probe) through the SAME campaign machinery — the noise quantity is a
-runtime operand of the kernel itself, so the whole sweep compiles ≤2 Pallas
-executables per mode. It runs on the TPU; ``--backend interpret`` runs the
-kernels in the Pallas interpreter instead (tests, docs):
+runtime operand of the kernel itself, so the whole sweep and its payload
+check compile one Pallas executable per mode. It runs on the TPU;
+``--backend interpret`` runs the kernels in the Pallas interpreter instead
+(tests, docs):
 
     PYTHONPATH=src python -m repro.launch.probe --pallas spmxv \
         [--backend interpret] [--modes fp,vmem] [--store PATH] \
@@ -224,8 +225,8 @@ def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
                  backend: str = "pallas") -> None:
     """Run the paper's methodology against a real Pallas kernel (on the
     TPU, or in the interpreter with ``backend="interpret"``). The sweep
-    rides the compile-once runtime-k path: ≤2 Pallas executables per
-    (kernel, mode)."""
+    rides the compile-once runtime-k path: one Pallas executable per
+    (kernel, mode), payload check included."""
     from repro.kernels.region import KERNEL_MODES, SIZE_DEFAULT, validate_size
 
     if kernel not in KERNEL_MODES:

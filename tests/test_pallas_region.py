@@ -1,14 +1,18 @@
 """The Pallas layer on the Controller/Campaign spine: compile-count
-guarantees (≤2 executables per (kernel, mode) sweep), oracle payload
-verification, campaign persist/replay with zero new measurements, and
-multi-size families sharing one store namespace."""
+guarantees (one executable per (kernel, mode) sweep, payload check
+included), oracle payload verification, campaign persist/replay with zero
+new measurements, and multi-size families sharing one store namespace."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import Campaign, Controller
-from repro.kernels.region import (KERNEL_MODES, family_names, oracle_rtol,
-                                  pallas_family, pallas_region, validate_size)
+from repro.kernels import region as region_mod
+from repro.kernels.region import (CHECK_K_MAX, KERNEL_MODES, family_names,
+                                  oracle_rtol, pallas_family, pallas_region,
+                                  validate_size)
 from repro.kernels.spmv_ell.ref import (fp_noise_ell_ref, make_band_ell,
                                         vmem_noise_ell_ref)
 
@@ -32,20 +36,50 @@ SIZES = {
 
 @pytest.mark.parametrize("kernel", sorted(KERNEL_MODES))
 def test_pallas_sweep_compiles_at_most_two_per_mode(kernel):
-    """Acceptance: a full k-sweep over a Pallas region builds ≤2 executables
-    per (kernel, mode) — the runtime-k sweep + the static payload check —
-    extending the ≤2-executables guarantee from the loop/graph layers."""
+    """Acceptance: a full k-sweep over a Pallas region, its sensitivity
+    probe and its payload check build exactly ONE executable per (kernel,
+    mode) — the runtime-k build the sweep times also counts the patterns —
+    inside the ≤2-executables guarantee of the loop/graph layers."""
     region, traces = _counting_region(kernel, **SIZES[kernel])
     ctl = Controller(reps=2, compile_once=True)
     before = 0
     for mode in KERNEL_MODES[kernel]:
+        ctl.probe_sensitivity(region, mode)
         res = ctl.run_mode(region, mode, ks=(0, 1, 2, 4, 8, 16))
         built = traces["n"] - before
         before = traces["n"]
-        assert built <= 2, f"{kernel}/{mode}: {built} executables for a sweep"
+        assert built == 1, f"{kernel}/{mode}: {built} executables for a sweep"
         assert len(res.curve.ks) >= 3
         assert res.injection is not None          # oracle payload check ran
         assert res.injection.payload == res.injection.expected > 0
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_MODES))
+def test_pallas_payload_check_after_the_sweep_traces_nothing(kernel):
+    """The payload check runs the executable the sweep timed: after
+    ``run_mode`` it traces nothing new, at a k below and above the cap."""
+    region, traces = _counting_region(kernel, **SIZES[kernel])
+    ctl = Controller(reps=2, verify_payload=False, stop_ratio=100.0)
+    mode = KERNEL_MODES[kernel][0]
+    ctl.run_mode(region, mode, ks=(0, 2, 4))
+    assert traces["n"] == 1
+    for k in (4, CHECK_K_MAX + 4):
+        assert region.payload_check(mode, k).ok()
+    assert traces["n"] == 1
+
+
+def test_pallas_fallback_payload_check_builds_one_runtime_k_executable():
+    """On the trace-per-k path the check builds the runtime-k executable
+    once, in place of a static build: one more than the sweep alone."""
+    built = {}
+    for verify in (False, True):
+        region, traces = _counting_region("probe", n_steps=8)
+        ctl = Controller(reps=2, compile_once=False, stop_ratio=100.0,
+                         verify_payload=verify)
+        res = ctl.run_mode(region, "fp", ks=(0, 2, 4))
+        built[verify] = traces["n"]
+    assert res.injection.payload == res.injection.expected == 4
+    assert built[True] == built[False] + 1
 
 
 def test_pallas_fallback_compiles_per_k():
@@ -100,6 +134,38 @@ def test_spmxv_oracle_separates_a_missing_pattern(mode, k_off):
     want = oracle(vals, 16, 128)
     assert not np.allclose(oracle(vals, 16 + k_off, 128), want,
                            rtol=oracle_rtol(16 * 256), atol=1e-5)
+
+
+def _one_pattern_short(monkeypatch, kernel):
+    """Make every ``kernel`` region built from here on run one noise pattern
+    fewer than its runtime-k callable is asked for: a dead payload."""
+    make_spec = region_mod._SPECS[kernel]
+
+    def short_spec(interpret, **sizes):
+        spec = make_spec(interpret, **sizes)
+
+        def rt_fn(mode):
+            fn = spec.rt_fn(mode)
+            return lambda k, *args: fn(jnp.maximum(k - 1, 0), *args)
+
+        return dataclasses.replace(spec, rt_fn=rt_fn)
+
+    monkeypatch.setitem(region_mod._SPECS, kernel, short_spec)
+
+
+@pytest.mark.parametrize("mode", sorted(KERNEL_MODES["spmxv"]))
+@pytest.mark.parametrize("k", [6, CHECK_K_MAX + 4])
+def test_runtime_k_payload_check_catches_a_dead_pattern(monkeypatch, mode, k):
+    """The check counts patterns on the runtime-k build: a build one pattern
+    short fails it, the unaltered build passes at min(k, CHECK_K_MAX)."""
+    region, _ = _counting_region("spmxv", n=256)
+    rep = region.payload_check(mode, k)
+    assert rep.payload == rep.expected == min(k, CHECK_K_MAX)
+    assert rep.ok()
+
+    _one_pattern_short(monkeypatch, "spmxv")
+    short, _ = _counting_region("spmxv", n=256)
+    assert not short.payload_check(mode, k).ok()
 
 
 def test_pallas_region_rejects_unknown_mode():
