@@ -51,37 +51,6 @@ def run_decan_stored(target, *, reps: int, inner: int = 1) -> Any:
     return res
 
 
-def pallas_sweep_ab(kernel: str, mode: str, ks, *, reps: int = 2,
-                    **sizes) -> dict:
-    """Wall-clock one (kernel, mode) k-sweep on the compile-once runtime-k
-    path vs the trace-per-k fallback (the paper's cost model), counting the
-    Pallas executables each path builds. The acceptance numbers for the
-    fig4/fig7 ``--pallas`` studies."""
-    from repro.core import Controller
-    from repro.kernels.region import pallas_region
-
-    out: dict = {}
-    for path, compile_once in (("compile_once", True), ("trace_per_k", False)):
-        traces = {"n": 0}
-        region = pallas_region(
-            kernel, backend="interpret",
-            trace_hook=lambda: traces.__setitem__("n", traces["n"] + 1),
-            **sizes)
-        ctl = Controller(reps=reps, compile_once=compile_once,
-                         verify_payload=False, stop_ratio=100.0)
-        with timer() as t:
-            ctl.run_mode(region, mode, ks=ks)
-        out[path] = {"seconds": round(t.dt, 3), "executables": traces["n"]}
-    out["speedup"] = round(out["trace_per_k"]["seconds"]
-                           / max(out["compile_once"]["seconds"], 1e-9), 2)
-    print(f"  [{kernel}/{mode} sweep over {len(list(ks))} ks: compile-once "
-          f"{out['compile_once']['executables']} executable(s) in "
-          f"{out['compile_once']['seconds']:.2f}s vs trace-per-k "
-          f"{out['trace_per_k']['executables']} in "
-          f"{out['trace_per_k']['seconds']:.2f}s -> {out['speedup']:.1f}x]")
-    return out
-
-
 def save(name: str, payload: Any) -> None:
     os.makedirs(OUT_DIR, exist_ok=True)
     with open(os.path.join(OUT_DIR, f"{name}.json"), "w") as f:

@@ -7,14 +7,13 @@ version uses the hardware efficiently — a single noise pattern already costs
 time (near-zero absorption in every mode).
 
 ``--pallas``: additionally run the study on the REAL tiled Pallas matmul
-kernel (in the Pallas interpreter) through the campaign spine, and report the
-compile-once vs trace-per-k sweep cost (executables built + wall-clock).
+kernel (in the Pallas interpreter) through the campaign spine.
 """
 from __future__ import annotations
 
 import argparse
 
-from benchmarks.common import banner, characterize, pallas_sweep_ab, save
+from benchmarks.common import banner, characterize, save
 from repro.bench.kernels import matmul_region
 from repro.core import Controller
 
@@ -29,10 +28,8 @@ def run_pallas(quick: bool = True) -> dict:
     region = pallas_region("matmul", backend="interpret", n=n)
     rep = characterize(ctl, region, ("fp", "vmem"))
     print(rep.summary())
-    ks = (0, 1, 2, 4, 8, 16) if quick else (0, 1, 2, 4, 8, 16, 32, 64)
-    ab = pallas_sweep_ab("matmul", "fp", ks, reps=2 if quick else 3, n=n)
     return {"region": region.name, "abs": rep.absorptions(),
-            "bottleneck": rep.bottleneck.label, "sweep_cost": ab}
+            "bottleneck": rep.bottleneck.label}
 
 
 def run(quick: bool = True, pallas: bool = False) -> dict:

@@ -8,14 +8,13 @@ absorption first DROPS (bandwidth regime tightening) then RISES again
 invisible to plain performance numbers.
 
 ``--pallas``: additionally run the q-sweep on the REAL ELL SPMV Pallas
-kernel (in the Pallas interpreter) through the campaign spine, and report the
-compile-once vs trace-per-k sweep cost (executables built + wall-clock).
+kernel (in the Pallas interpreter) through the campaign spine.
 """
 from __future__ import annotations
 
 import argparse
 
-from benchmarks.common import banner, characterize, pallas_sweep_ab, save
+from benchmarks.common import banner, characterize, save
 from repro.bench.kernels import spmxv_region
 from repro.core import Controller, measure
 
@@ -45,10 +44,7 @@ def run_pallas(quick: bool = True) -> dict:
         print(f"  pallas q={q:4.2f}  {gflops:6.3f} GFLOP/s  "
               f"Abs_FP={r['abs_fp']:6.1f} Abs_VMEM={r['abs_vmem']:6.1f} "
               f"-> {r['label']}")
-    ks = (0, 1, 2, 4, 8, 16) if quick else (0, 1, 2, 4, 8, 16, 32, 64)
-    ab = pallas_sweep_ab("spmxv", "fp", ks, reps=2 if quick else 3,
-                         n=n, nnz_per_row=nnz)
-    return {"rows": rows, "sweep_cost": ab}
+    return {"rows": rows}
 
 
 def run(quick: bool = True, pallas: bool = False) -> dict:

@@ -71,8 +71,7 @@ def main() -> None:
                     help="measure every point afresh (no persistence)")
     ap.add_argument("--pallas", action="store_true",
                     help="also run fig4/fig7 on the real Pallas kernels "
-                         "(in the Pallas interpreter) and report the "
-                         "compile-once vs trace-per-k sweep cost")
+                         "(in the Pallas interpreter)")
     ap.add_argument("--emit-fleet-plan", default=None, metavar="PATH",
                     help="write a repro.fleet SweepPlan covering the "
                          "fig4/fig7 Pallas size/q families to PATH and "

@@ -9,7 +9,8 @@ import jax
 
 from repro.configs import TPU_V5E, get_smoke_config
 from repro.configs.base import ShapeConfig
-from repro.core import (StepTerms, classify, predict_absorption, probe_step)
+from repro.core import (Controller, StepTerms, classify, predict_absorption,
+                        step_region)
 from repro.core.noise import NoiseScale, make_modes
 from repro.models.model import build
 
@@ -21,14 +22,17 @@ batch = api.dummy_batch(ShapeConfig("probe", "train", 128, 4))
 modes = make_modes(NoiseScale(mxu_dim=64, hbm_mib=16, chase_len=1 << 18))
 
 print("== measured (host backend, reduced config) ==")
+names = ("fp_add32", "mxu_fma128", "vmem_ld", "hbm_stream")
+region = step_region("gemma_2b_train", lambda p, b: api.loss(p, b)[0],
+                     (params, batch), {name: modes[name] for name in names})
+ctl = Controller(reps=3)
 absorptions = {}
-for name in ("fp_add32", "mxu_fma128", "vmem_ld", "hbm_stream"):
-    pr = probe_step(lambda p, b: api.loss(p, b)[0], (params, batch),
-                    modes[name], reps=3)
-    absorptions[name] = pr.fit.k1
-    print(f"  {name:12s} Abs^raw={pr.fit.k1:7.1f}  "
-          f"payload={pr.injection.payload}/{pr.injection.expected} "
-          f"overhead={pr.injection.overhead_fraction:.0%}")
+for name in names:
+    res = ctl.run_mode(region, name)
+    absorptions[name] = res.fit.k1
+    print(f"  {name:12s} Abs^raw={res.fit.k1:7.1f}  "
+          f"payload={res.injection.payload}/{res.injection.expected} "
+          f"overhead={res.injection.overhead_fraction:.0%}")
 print(" ", classify(absorptions))
 
 print("\n== analytic (full gemma-2b train_4k on 256x TPU v5e) ==")
@@ -44,4 +48,4 @@ for name in ("fp_add32", "mxu_fma128", "vmem_ld", "hbm_stream"):
 print(" ", classify(pred, high=1000.0))
 print("\nThe memory term dominates at full scale (XLA attention materializes")
 print("score tensors) -> hbm_stream noise is not absorbed; that is the")
-print("bottleneck the flash-attention path removes (EXPERIMENTS.md §Perf).")
+print("bottleneck the flash-attention path removes.")

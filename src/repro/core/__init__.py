@@ -47,7 +47,7 @@ from repro.core.quality import (QualityPolicy, RemeasureBudget,  # noqa: F401
                                 measure_quality, quality_from_dict)
 from repro.core.controller import Controller, RegionReport, RegionTarget, loop_region  # noqa: F401
 from repro.core.decan import DecanResult, DecanTarget, run_decan  # noqa: F401
-from repro.core.injector import (inject, inject_rt, init_state, probe_step,  # noqa: F401
+from repro.core.injector import (inject, inject_rt, init_state,  # noqa: F401
                                  step_region, verify_semantics)
 from repro.core.loopnoise import LoopNoise, make_loop_modes, noisy_loop  # noqa: F401
 from repro.core.noise import NOISE_SCOPE, NoiseMode, NoiseScale, PatternCost, make_modes  # noqa: F401

@@ -343,32 +343,29 @@ def assemble_curve(mode: str, ks: Sequence[int], ts: Sequence[float], *,
                            stopped_early=stopped_early)
 
 
-def sweep(build: Callable[[int], Callable], *, mode: str = "",
-          ks: Sequence[int] = DEFAULT_KS, args_for: Optional[Callable] = None,
+def sweep(fn: Callable, *, args_for: Callable[[int], tuple], mode: str = "",
+          ks: Sequence[int] = DEFAULT_KS,
           reps: int = 5, inner: int = 1, stop_ratio: float = 4.0,
           stop_consecutive: int = STOP_CONSECUTIVE,
           drift_correct: bool = True) -> AbsorptionCurve:
     """Measure t(k) for increasing noise quantities.
 
-    ``build(k)`` returns the jitted noisy callable; ``args_for(k)`` its args.
+    ``fn`` is the region's runtime-k callable, timed at every k;
+    ``args_for(k)`` its arguments, k among them.
     Online saturation detection (paper §3.1): stop once ``stop_consecutive``
     successive points exceed ``stop_ratio``×t(0) — the tail is already in the
     linear regime and further points only cost experiment time.
 
-    drift_correct: on shared/throttled machines the baseline drifts between
-    builds; the k=0 kernel is re-timed after the sweep and a linear drift
+    drift_correct: on shared/throttled machines the baseline drifts during
+    the sweep; the k=0 point is re-timed after the sweep and a linear drift
     factor is divided out (two-point correction).
     """
     out_ks: list[int] = []
     out_ts: list[float] = []
     n_over = 0
     stopped = False
-    base_fn = build(ks[0]) if drift_correct else None
-    base_args = (args_for(ks[0]) if args_for else ()) if drift_correct else ()
     for k in ks:
-        fn = build(k)
-        a = args_for(k) if args_for else ()
-        t = measure(fn, a, reps=reps, inner=inner)
+        t = measure(fn, args_for(k), reps=reps, inner=inner)
         out_ks.append(k)
         out_ts.append(t)
         if t / floor_time(out_ts[0], f"sweep({mode}) t(k=0)") > stop_ratio:
@@ -379,7 +376,7 @@ def sweep(build: Callable[[int], Callable], *, mode: str = "",
         else:
             n_over = 0
     if drift_correct and len(out_ts) > 2:
-        t0_end = measure(base_fn, base_args, reps=max(reps - 2, 2),
+        t0_end = measure(fn, args_for(out_ks[0]), reps=max(reps - 2, 2),
                          inner=inner)
         drift = t0_end / floor_time(out_ts[0], f"sweep({mode}) t(k=0)")
         out_ts = drift_corrected(out_ts, drift)

@@ -112,9 +112,7 @@ def forced_regime(base, name: str, shapes: dict) -> "object":
         return args if shape is None else (*args, shape)
 
     def build_rt(mode: str):
-        inner = base.build_rt(mode) if base.build_rt is not None else None
-        if inner is None:
-            return None
+        inner = base.build_rt(mode)
 
         def fn(k, *args):
             return inner(k, *_strip(args))

@@ -23,12 +23,16 @@ instead of a transient loop:
     curves live in one artifact, and ``core.decan`` variant timings persist
     as ``decan`` records — one file holds a region's full dossier.
 
-Combined with the controller's compile-once path (one runtime-k executable
-per sweep) this turns the slowest loop in the repo — recompile-per-(mode, k)
-— into a cached, restartable pipeline.
+Combined with the controller's runtime-k sweep (one executable per (region,
+mode)) this turns the slowest loop in the repo — recompile-per-(mode, k) —
+into a cached, restartable pipeline.
 
 Store schema (one JSON object per line):
-  {"kind": "meta",   "region": r, "mode": m, "reps": n, "compile_once": b}
+  {"kind": "meta",   "region": r, "mode": m, "reps": n, "compile_once": true}
+                                                # compile_once is always
+                                                # written true (every sweep
+                                                # takes k at run time); stores
+                                                # holding false still replay
   {"kind": "sens",   "region": r, "mode": m, "value": s}
   {"kind": "point",  "region": r, "mode": m, "k": k, "t": seconds}  # raw t
   {"kind": "done",   "region": r, "mode": m, "ks": [...], "drift": f|null,
@@ -110,6 +114,8 @@ import os
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Mapping, Optional, Sequence
+
+import jax.numpy as jnp
 
 from repro.core.absorption import (DEFAULT_KS, STOP_CONSECUTIVE,
                                    AbsorptionFit, MeasureTimeout, absorption,
@@ -657,8 +663,7 @@ class Campaign:
     ``workers`` > 1 fans independent (region, mode) sweeps across a thread
     pool; every timed section still serializes through one lock (wall-clock
     measurements on a shared machine must not overlap), so extra workers buy
-    back the compile/verify time, which dominates on the trace-per-k fallback
-    path and still bounds campaign latency on the compile-once path.
+    back the compile/verify time, which bounds campaign latency.
 
     Multi-host fan-out: give each host its own store (``worker_store``) and a
     disjoint slice of the grid via ``measure_shard``; ``merge_stores`` then
@@ -703,22 +708,22 @@ class Campaign:
 
     # -- one (region, mode) sweep, store-backed -----------------------------
     def _check_meta(self, target: RegionTarget, mode: str) -> None:
-        """Stored timings are only reusable under the same measurement
-        settings; on mismatch, discard the pair and remeasure."""
+        """Stored timings are only reusable under the same ``reps``; on
+        mismatch, discard the pair and remeasure. The meta record's
+        ``compile_once`` is written true and never compared: every sweep
+        delivers k at run time, and pairs stored with false replay."""
         key = (target.name, mode)
-        cur = {"reps": self.ctl.reps,
-               "compile_once": self.ctl._rt_fn(target, mode) is not None}
         old = self.store.meta.get(key)
-        if old is not None and any(old.get(f) != cur[f] for f in cur):
+        if old is not None and old.get("reps") != self.ctl.reps:
             log.warning(
-                "campaign store for %s/%s was measured with %s, current "
-                "settings are %s; discarding stored sweep and remeasuring",
-                target.name, mode,
-                {f: old.get(f) for f in cur}, cur)
+                "campaign store for %s/%s was measured with reps=%s, current "
+                "reps=%s; discarding stored sweep and remeasuring",
+                target.name, mode, old.get("reps"), self.ctl.reps)
             self.store.discard(*key)
         if self.store.meta.get(key) is None:
             self.store.append({"kind": "meta", "region": target.name,
-                               "mode": mode, **cur})
+                               "mode": mode, "reps": self.ctl.reps,
+                               "compile_once": True})
 
     def _sensitivity(self, target: RegionTarget, mode: str) -> float:
         key = (target.name, mode)
@@ -744,12 +749,6 @@ class Campaign:
         return self.quality.deadline(t0, stop_ratio=self.ctl.stop_ratio,
                                      reps=self.ctl.reps, warmup=2)
 
-    def _point_fn(self, target: RegionTarget, mode: str, fn_rt, k: int):
-        if fn_rt is not None:
-            import jax.numpy as jnp
-            return fn_rt, (jnp.int32(k), *target.args_for_rt(mode))
-        return target.build(mode, k), target.args_for(mode, k)
-
     def _quality_rec(self, region: str, mode: str, k: int, verdict: str,
                      reason: Optional[str], *, spread: Optional[float] = None,
                      reps: Optional[int] = None,
@@ -764,9 +763,9 @@ class Campaign:
         generalization of the end-of-sweep two-point drift check). A reading
         outside ``sentinel_tol`` means something changed under the sweep —
         quarantine ONLY the span of fresh points since the last sentinel."""
-        fn, a = self._point_fn(target, mode, fn_rt, k0)
+        a = (jnp.int32(k0), *target.args_for_rt(mode))
         with self._measure_lock, spans.span("campaign.drift", k=k0):
-            t = measure(fn, a, reps=max(self.ctl.reps - 2, 2),
+            t = measure(fn_rt, a, reps=max(self.ctl.reps - 2, 2),
                         deadline=self._deadline(t0))
         self._note(measured=1)
         ratio = t / floor_time(t0, f"campaign({target.name}/{mode}) t(k=0)")
@@ -811,6 +810,7 @@ class Campaign:
             for qk in self.store.quarantined_ks(*key):
                 stored.pop(qk, None)     # quarantined points re-measure
         fn_rt = self.ctl._rt_fn(target, mode)
+        args_rt = target.args_for_rt(mode)
 
         out_ks: list[int] = []
         out_ts: list[float] = []
@@ -826,10 +826,10 @@ class Campaign:
                 t = stored[k]
                 self._note(cached=1)
             elif self.quality is None:
-                fn, a = self._point_fn(target, mode, fn_rt, k)
+                a = (jnp.int32(k), *args_rt)
                 with self._measure_lock, \
                         spans.span("campaign.point", k=k, reps=self.ctl.reps):
-                    t = measure(fn, a, reps=self.ctl.reps)
+                    t = measure(fn_rt, a, reps=self.ctl.reps)
                 self._note(measured=1)
                 n_fresh += 1
                 self.store.append({"kind": "point", "region": target.name,
@@ -838,10 +838,10 @@ class Campaign:
                 # quality-guarded point: dispersion-gated sample under the
                 # re-measure budget, on a watchdog deadline derived from
                 # the worst time the online stop rule would accept
-                fn, a = self._point_fn(target, mode, fn_rt, k)
+                a = (jnp.int32(k), *args_rt)
                 deadline = self._deadline(out_ts[0] if out_ts else None)
 
-                def once(n: int, _fn=fn, _a=a, _dl=deadline):
+                def once(n: int, _fn=fn_rt, _a=a, _dl=deadline):
                     return measure_sample(_fn, _a, reps=n, deadline=_dl)
 
                 try:
@@ -895,9 +895,9 @@ class Campaign:
         # store; the factor is recorded so replays reproduce this curve.
         drift = None
         if n_fresh == len(out_ks) and len(out_ts) > 2 and not timed_out:
-            fn, a = self._point_fn(target, mode, fn_rt, out_ks[0])
+            a = (jnp.int32(out_ks[0]), *args_rt)
             with self._measure_lock, spans.span("campaign.drift", k=out_ks[0]):
-                t0_end = measure(fn, a, reps=max(self.ctl.reps - 2, 2),
+                t0_end = measure(fn_rt, a, reps=max(self.ctl.reps - 2, 2),
                                  deadline=self._deadline(out_ts[0]))
             self._note(measured=1)
             drift = t0_end / floor_time(

@@ -4,14 +4,14 @@ saturation detection, execution clustering, payload verification, and
 classification.
 
 The paper's controller rebuilds the target application per (mode, k) — its own
-criteria table concedes the cost ("Fast: ✗"). This controller escapes it: on
-the compile-once path the noise quantity k is a RUNTIME operand of one jitted
-executable per (region, mode) (``RegionTarget.build_rt``), so a whole k-sweep
-compiles O(1) executables instead of O(len(ks)). The trace-per-k path is kept
-as a fallback for regions that cannot thread a traced k, and the paper's
-mitigations still apply on both paths (probe first with one or two quantities;
-coarse steps of 5–10 for robust loops; stop the sweep online once saturation
-is evident).
+criteria table concedes the cost ("Fast: ✗"). This controller escapes it: the
+noise quantity k is a RUNTIME operand of one jitted executable per (region,
+mode) (``RegionTarget.build_rt``), so a whole k-sweep compiles O(1)
+executables instead of O(len(ks)). That is the only way a sweep delivers k:
+a region without ``build_rt`` can be replayed from a campaign store but not
+measured. The paper's mitigations still apply (probe first with one or two
+quantities; coarse steps of 5–10 for robust loops; stop the sweep online once
+saturation is evident).
 """
 from __future__ import annotations
 
@@ -41,15 +41,17 @@ class PayloadError(RuntimeError):
 class RegionTarget:
     """One noisable region (the paper: a loop nest selected by pragma/config).
 
-    ``build(mode_name, k)`` returns the jitted noisy callable;
+    ``build(mode_name, k)`` returns a static-k build and
     ``args_for(mode_name, k)`` its arguments. ``build("", 0)`` must be the
     clean reference. ``body_size``: |l1.l2| for Abs^rel; 0 = derive from HLO.
+    Static builds serve what needs a k baked into the HLO: the clean
+    reference, the body size, the static audit and the default payload
+    check. No sweep times them.
 
-    Compile-once sweeps (optional): ``build_rt(mode_name)`` returns ONE jitted
-    callable taking ``(k, *args_for_rt(mode_name))`` with k an int32 runtime
-    operand (or None when the mode doesn't support it); the controller then
-    sweeps k without retracing. Regions without ``build_rt`` use the
-    trace-per-k fallback.
+    ``build_rt(mode_name)`` returns ONE jitted callable taking ``(k,
+    *args_for_rt(mode_name))`` with k an int32 runtime operand; every sweep
+    times it. A target without ``build_rt`` (a store replay that must never
+    build) is refused by the Controller the moment a sweep would measure.
 
     ``payload_check(mode_name, k)`` (optional) overrides the default
     HLO-scope-counting payload verification with a region-specific static
@@ -68,7 +70,7 @@ class RegionTarget:
     args_for: Callable[[str, int], tuple]
     body_size: int = 0
     payload_target: dict[str, str] = dataclasses.field(default_factory=dict)
-    build_rt: Optional[Callable[[str], Optional[Callable]]] = None
+    build_rt: Optional[Callable[[str], Callable]] = None
     args_for_rt: Optional[Callable[[str], tuple]] = None
     payload_check: Optional[Callable[[str, int], object]] = None
     audit_hint: Optional[dict] = None
@@ -150,13 +152,12 @@ class Controller:
 
     def __init__(self, *, tol: float = 0.05, reps: int = 5,
                  probe_k: int = 24, stop_ratio: float = 4.0,
-                 verify_payload: bool = True, compile_once: bool = True):
+                 verify_payload: bool = True):
         self.tol = tol
         self.reps = reps
         self.probe_k = probe_k            # paper: "values around 20 or 30"
         self.stop_ratio = stop_ratio
         self.verify_payload = verify_payload
-        self.compile_once = compile_once  # use build_rt when the region has it
         # memoize runtime-k callables per (target, mode): build_rt returns a
         # fresh jit wrapper each call, and jax's compile cache keys on the
         # callable's identity — without this the sensitivity probe and the
@@ -165,12 +166,14 @@ class Controller:
         # different buffers); the entry pins the target so its id() cannot
         # be recycled onto a stale executable.
         self._rt_cache: dict[tuple[int, str],
-                             tuple[RegionTarget, Optional[Callable]]] = {}
+                             tuple[RegionTarget, Callable]] = {}
 
-    def _rt_fn(self, target: RegionTarget, mode: str) -> Optional[Callable]:
-        """The region's runtime-k callable, or None -> trace-per-k fallback."""
-        if not self.compile_once or target.build_rt is None:
-            return None
+    def _rt_fn(self, target: RegionTarget, mode: str) -> Callable:
+        """The region's runtime-k callable; a ValueError for a region that
+        has none, before anything is measured."""
+        if target.build_rt is None:
+            raise ValueError(f"region {target.name!r} has no runtime-k "
+                             "build; it can be replayed, not measured")
         key = (id(target), mode)
         if key not in self._rt_cache:
             self._rt_cache[key] = (target, target.build_rt(mode))
@@ -181,18 +184,11 @@ class Controller:
                           deadline: Optional[float] = None) -> float:
         reps = max(2, self.reps - 2)
         fn_rt = self._rt_fn(target, mode)
-        if fn_rt is not None:
-            args = target.args_for_rt(mode)
-            t0 = measure(fn_rt, (jnp.int32(0), *args), reps=reps,
-                         deadline=deadline)
-            tk = measure(fn_rt, (jnp.int32(self.probe_k), *args), reps=reps,
-                         deadline=deadline)
-        else:
-            t0 = measure(target.build(mode, 0), target.args_for(mode, 0),
-                         reps=reps, deadline=deadline)
-            tk = measure(target.build(mode, self.probe_k),
-                         target.args_for(mode, self.probe_k), reps=reps,
-                         deadline=deadline)
+        args = target.args_for_rt(mode)
+        t0 = measure(fn_rt, (jnp.int32(0), *args), reps=reps,
+                     deadline=deadline)
+        tk = measure(fn_rt, (jnp.int32(self.probe_k), *args), reps=reps,
+                     deadline=deadline)
         return tk / floor_time(t0, f"probe_sensitivity({target.name}/{mode}) t0")
 
     def _ks_for(self, sensitivity: float) -> Sequence[int]:
@@ -205,27 +201,22 @@ class Controller:
 
     def run_mode(self, target: RegionTarget, mode: str,
                  ks: Optional[Sequence[int]] = None) -> ModeResult:
-        """Sweep one mode. Compile-once path: the sensitivity probe and every
-        sweep point reuse ONE runtime-k executable; payload verification
-        adds one static-k executable for HLO-counted regions (at most 2
-        compilations for the whole sweep) and none for Pallas regions, whose
-        check runs that runtime-k executable (the fallback path compiles one
-        per k, the paper's cost model).
+        """Sweep one mode. The sensitivity probe and every sweep point reuse
+        ONE runtime-k executable; payload verification adds one static-k
+        executable for HLO-counted regions (at most 2 compilations for the
+        whole sweep) and none for Pallas regions, whose check runs that
+        runtime-k executable. A region without ``build_rt`` raises a
+        ValueError before anything is measured.
 
         ``ks``: override the sensitivity-chosen quantities (campaign resume).
         """
         fn_rt = self._rt_fn(target, mode)
         if ks is None:
             ks = self._ks_for(self.probe_sensitivity(target, mode))
-        if fn_rt is not None:
-            args_rt = target.args_for_rt(mode)
-            curve = sweep(lambda k: fn_rt, mode=mode, ks=ks,
-                          args_for=lambda k: (jnp.int32(k), *args_rt),
-                          reps=self.reps, stop_ratio=self.stop_ratio)
-        else:
-            curve = sweep(lambda k: target.build(mode, k), mode=mode, ks=ks,
-                          args_for=lambda k: target.args_for(mode, k),
-                          reps=self.reps, stop_ratio=self.stop_ratio)
+        args_rt = target.args_for_rt(mode)
+        curve = sweep(fn_rt, mode=mode, ks=ks,
+                      args_for=lambda k: (jnp.int32(k), *args_rt),
+                      reps=self.reps, stop_ratio=self.stop_ratio)
         fit = absorption(curve, tol=self.tol)
         inj = self.verify_mode_payload(target, mode, curve.ks) \
             if self.verify_payload else None
@@ -233,9 +224,9 @@ class Controller:
 
     def verify_mode_payload(self, target: RegionTarget, mode: str,
                             ks: Sequence[int]):
-        """Static payload check (§2.3) on a trace-per-k executable — the HLO
-        of the runtime-k path holds ONE pattern in a loop body, so surviving
-        ops must be counted on a static unrolled trace. Regions with a
+        """Static payload check (§2.3) on a static-k build — the HLO of the
+        runtime-k build holds ONE pattern in a loop body, so surviving ops
+        must be counted on a static unrolled trace. Regions with a
         ``payload_check`` override verify their own way instead: Pallas
         kernels hold the runtime-k build's noise accumulator to an exact
         oracle, so their check needs no static build.
@@ -314,7 +305,7 @@ def loop_region(name: str, make_fn: Callable[[Optional[LoopNoise], int], Callabl
     jitted fn whose last positional arg is the noise carry (or no extra arg
     when noise is None).
 
-    Compile-once support comes for free as long as ``make_fn`` passes its k
+    The runtime-k build comes for free as long as ``make_fn`` passes its k
     straight through to ``noise.emit(carry, k, i)`` (the documented contract):
     ``build_rt`` hands make_fn a LoopNoise whose emit ignores that static k and
     runs the runtime-k emitter with a k captured from the jitted signature.
@@ -336,8 +327,6 @@ def loop_region(name: str, make_fn: Callable[[Optional[LoopNoise], int], Callabl
 
     def build_rt(mode: str):
         noise = modes[mode]
-        if noise.emit_rt is None:
-            return None
 
         def fn(k, *args_and_carry):
             rt_noise = dataclasses.replace(

@@ -2,10 +2,11 @@
 k patterns of a noise mode.
 
 This is the coarse-grained injection site: noise and step co-exist in one XLA
-program, competing for the same chip resources under XLA's static schedule
-(the TPU's "absorber"; DESIGN.md §6.3). The noise state is threaded through
-the wrapped step so buffers are allocated once and patterns chain across
-calls; the scalar aux output is the ``volatile`` analogue (DCE-proof).
+program, competing for the same chip resources under XLA's static schedule,
+which plays the part of the CPU's out-of-order window in absorbing the
+noise. The noise state is threaded through the wrapped step so buffers are
+allocated once and patterns chain across calls; the scalar aux output is the
+``volatile`` analogue (DCE-proof).
 
 Semantics preservation is by construction: noise reads/writes only its own
 state (R_n ∩ R_s = ∅) and the original outputs are returned untouched —
@@ -15,14 +16,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional, Sequence
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import payload as payload_mod
-from repro.core.absorption import (DEFAULT_KS, AbsorptionCurve, AbsorptionFit,
-                                   absorption, sweep)
 from repro.core.noise import NoiseMode
 
 
@@ -45,16 +44,13 @@ def inject(step_fn: Callable, mode: NoiseMode, k: int) -> Callable:
 
 
 def inject_rt(step_fn: Callable, mode: NoiseMode) -> Callable:
-    """Compile-once variant of ``inject``: the noise quantity is a runtime
+    """Runtime-k variant of ``inject``: the noise quantity is a runtime
     operand, so ONE jitted executable serves the whole k-sweep.
 
     Returns ``noisy(k, noise_state, *args, **kw) -> (out, aux, new_state)``
     where ``k`` is an int32 scalar (traced under jit). k leads so region
     adapters share one calling convention: ``build_rt(mode)(k, *args_rt)``.
     """
-    if mode.apply_rt is None:
-        raise ValueError(f"mode {mode.name!r} has no runtime-k apply")
-
     def noisy(k, noise_state, *args, **kw):
         out = step_fn(*args, **kw)
         aux, new_state = mode.apply_rt(noise_state, k)
@@ -71,16 +67,18 @@ def init_state(mode: NoiseMode, rng: Optional[jax.Array] = None):
 def step_region(name: str, step_fn: Callable, args: tuple,
                 registry: dict[str, NoiseMode], *, body_size: int = 0,
                 rng: Optional[jax.Array] = None):
-    """Adapt a jitted step + graph-level noise registry into a RegionTarget
-    (with both the trace-per-k and the compile-once build paths).
+    """Adapt a jitted step + graph-level noise registry into a RegionTarget:
+    sweeps time the runtime-k build; the static-k build is the clean
+    reference and the payload check's census.
 
     The region's payload check runs what it checks: it counts the surviving
-    patterns of the static build at ``min(k, CHECK_K_MAX)``, runs that
-    build once, and holds its outputs, and those of the runtime-k build at
-    the swept k, to the outputs at k=0 (the runtime-k build's where the
-    mode has one, so the check compiles nothing the sweep did not, else
-    the clean step's): injection must leave them bit-identical
-    (``ref_err`` the worst relative difference, ``ref_tol`` 0)."""
+    patterns of the static build at ``min(k, CHECK_K_MAX)`` (patterns are
+    counted in unrolled HLO, which only a static k gives), runs that build
+    once, and holds its outputs, and those of the runtime-k build at the
+    swept k, to the runtime-k build's outputs at k=0, so the check compiles
+    nothing for the reference that the sweep did not: injection must leave
+    them bit-identical (``ref_err`` the worst relative difference,
+    ``ref_tol`` 0)."""
     from repro.core.controller import RegionTarget   # cycle: controller->here
 
     rng = jax.random.PRNGKey(0) if rng is None else rng
@@ -98,8 +96,6 @@ def step_region(name: str, step_fn: Callable, args: tuple,
 
     @functools.cache     # the payload check reuses the sweep's executable
     def build_rt(mode: str):
-        if registry[mode].apply_rt is None:
-            return None
         return jax.jit(inject_rt(step_fn, registry[mode]))
 
     def args_for_rt(mode: str):
@@ -118,11 +114,8 @@ def step_region(name: str, step_fn: Callable, args: tuple,
                 expected=k)
         with span("campaign.payload_check.reference"):
             rt = build_rt(mode)
-            if rt is None:
-                clean, got = build(mode, 0)(*args_for(mode, 0)), [out]
-            else:
-                clean = rt(jnp.int32(0), *args_for_rt(mode))[0]
-                got = [out, rt(jnp.int32(k_swept), *args_for_rt(mode))[0]]
+            clean = rt(jnp.int32(0), *args_for_rt(mode))[0]
+            got = [out, rt(jnp.int32(k_swept), *args_for_rt(mode))[0]]
             err = max(outputs_err(g, clean) for g in got)
         return dataclasses.replace(rep, ref_err=err, ref_tol=0.0)
 
@@ -145,55 +138,6 @@ def outputs_err(got, want) -> float:
         diff = float(jnp.max(jnp.abs(g - w)))
         worst = max(worst, diff / max(float(jnp.max(jnp.abs(w))), 1e-30))
     return worst
-
-
-@dataclasses.dataclass
-class StepProbe:
-    """Measured + statically-verified absorption of one step × one mode."""
-    mode: str
-    curve: AbsorptionCurve
-    fit: AbsorptionFit
-    injection: payload_mod.InjectionReport
-
-
-def probe_step(step_fn: Callable, args: tuple, mode: NoiseMode, *,
-               ks: Sequence[int] = DEFAULT_KS, reps: int = 5,
-               tol: float = 0.05, verify_payload: bool = True,
-               donate_state: bool = False,
-               compile_once: bool = True) -> StepProbe:
-    """Sweep k for ``mode`` against ``step_fn(*args)`` (measured on the host
-    backend) and statically verify the payload survived XLA optimization.
-
-    ``compile_once`` (default): k is a runtime operand, so the whole sweep
-    traces/compiles ONE executable instead of one per k (payload verification
-    still compiles one static-k executable — the count stays O(1), not
-    O(len(ks))). Falls back to trace-per-k when the mode has no runtime apply.
-    """
-    state0 = init_state(mode)
-
-    if compile_once and mode.apply_rt is not None:
-        fn_rt = jax.jit(inject_rt(step_fn, mode))  # noise state reused: no donation
-        curve = sweep(lambda k: fn_rt, mode=mode.name, ks=ks,
-                      args_for=lambda k: (jnp.int32(k), state0, *args),
-                      reps=reps)
-    else:
-        def build(k: int):
-            fn = inject(step_fn, mode, k)
-            return jax.jit(fn, donate_argnums=(0,) if donate_state else ())
-
-        curve = sweep(build, mode=mode.name, ks=ks,
-                      args_for=lambda k: (state0, *args), reps=reps)
-    fit = absorption(curve, tol=tol)
-
-    inj = None
-    if verify_payload:
-        k_chk = max(8, curve.ks[-1] // 2) if len(curve.ks) > 1 else 8
-        compiled = jax.jit(inject(step_fn, mode, k_chk)).lower(
-            state0, *args).compile()
-        inj = payload_mod.analyze_injection(
-            compiled.as_text(), mode=mode.name, target=mode.target,
-            expected=k_chk)
-    return StepProbe(mode=mode.name, curve=curve, fit=fit, injection=inj)
 
 
 def verify_semantics(step_fn: Callable, args: tuple, mode: NoiseMode,

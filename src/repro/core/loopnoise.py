@@ -19,7 +19,8 @@ Protocol (mirrors core.noise graph-level modes, but loop-carried):
                              static python int baked into the trace
   emit_rt(carry, k, i)    -> same patterns with k a RUNTIME operand (traced
                              int32, inner bounded ``lax.fori_loop``): one
-                             jitted executable serves the whole k-sweep
+                             jitted executable serves the whole k-sweep,
+                             and every sweep times it
   finalize(carry)         -> scalar aux (returned from the jitted function —
                              the `volatile` analogue: DCE-proof)
 
@@ -29,7 +30,7 @@ verification (core.payload) can count surviving ops in optimized HLO.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -48,8 +49,9 @@ class LoopNoise:
     emit: Callable[[Any, int, jax.Array], Any]
     finalize: Callable[[Any], jax.Array]
     payload_op: str = "add"           # dominant HLO opcode of one pattern
-    # runtime-k emitter (compile-once sweeps); None = trace-per-k only
-    emit_rt: Optional[Callable[[Any, jax.Array, jax.Array], Any]] = None
+    # runtime-k emitter: the one every sweep times (a required keyword)
+    emit_rt: Callable[[Any, jax.Array, jax.Array], Any] = \
+        dataclasses.field(kw_only=True)
     description: str = ""
 
 

@@ -3,21 +3,23 @@
 The paper injects assembly patterns (fp_add64, l1_ld64, memory_ld64) into loop
 bodies. On TPU the unit of overlap is not an OoO window but XLA's static
 schedule of MXU / VPU / DMA / ICI; the noise quantum is one HLO op group
-("pattern") rather than one instruction (DESIGN.md §2/§6). Each mode is:
+("pattern") rather than one instruction, because XLA schedules and fuses
+whole ops, never single instructions. Each mode is:
 
   make_state(rng)        allocate DISJOINT noise buffers (semantics preserving
                          by construction — the paper's R_n ∩ R_s = ∅ argument)
-  apply(state, k)        emit k patterns (k a static python int — the trace
-                         baked, trace-per-k path); returns (aux, new_state).
+  apply(state, k)        emit k patterns (k a static python int baked into
+                         the trace: the unrolled HLO the payload census
+                         counts); returns (aux, new_state).
                          ``aux`` is returned from the jitted step so XLA
                          cannot DCE the noise (the `volatile` analogue).
   apply_rt(state, k)     same patterns with k a RUNTIME operand (traced int32
                          scalar, bounded ``lax.fori_loop``) — one jitted
-                         executable serves a whole k-sweep (compile-once).
-                         For k >= 1 the emitted arithmetic matches ``apply``
-                         pattern-for-pattern, so both paths measure the same
-                         noise; only the k=0 aux differs (sum of carried
-                         accumulators instead of literal 0).
+                         executable serves a whole k-sweep; every sweep
+                         times this one. For k >= 1 the emitted arithmetic
+                         matches ``apply`` pattern-for-pattern, so the census
+                         counts what the sweep ran; only the k=0 aux differs
+                         (sum of carried accumulators instead of literal 0).
   pattern_cost(hw)       per-pattern resource cost (FLOPs / HBM bytes / ICI
                          bytes / serial latency) — drives the analytic
                          saturation model in core/analytic.py.
@@ -71,8 +73,8 @@ class NoiseMode:
     make_state: Callable[[jax.Array], Any]   # rng -> state pytree
     apply: Callable[[Any, int], tuple[jax.Array, Any]]
     pattern_cost: Callable[[Any], PatternCost]
-    # runtime-k variant (compile-once sweeps); None = trace-per-k only
-    apply_rt: Optional[Callable[[Any, jax.Array], tuple[jax.Array, Any]]] = None
+    # runtime-k variant: the one every sweep times
+    apply_rt: Callable[[Any, jax.Array], tuple[jax.Array, Any]]
     description: str = ""
 
 

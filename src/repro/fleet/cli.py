@@ -176,7 +176,6 @@ def _build_plan(args) -> "object":
                                                       f"{name}.jsonl"),
                      targets=[spec], reps=args.reps, shards=args.shards,
                      workers=args.workers,
-                     compile_once=not args.no_compile_once,
                      backend=args.backend,
                      launcher=_launcher_spec(args),
                      retry=_retry_spec(args),
@@ -624,8 +623,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="Pallas backend: pallas compiles for the TPU "
                          "and refuses to run without one; interpret runs the "
                          "Pallas interpreter")
-    pp.add_argument("--no-compile-once", action="store_true",
-                    help="force the trace-per-k fallback sweep path")
     pp.add_argument("--quality-policy", default=None, metavar="JSON",
                     help="serialize a runtime measurement-integrity policy "
                          "into the plan (inline JSON or a file): "

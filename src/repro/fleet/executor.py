@@ -390,7 +390,7 @@ def run_worker(plan: SweepPlan, *, index: Optional[int] = None,
     host = _handshake(plan)
     title = header or f"fleet plan {plan.name!r} [{plan.digest()}]"
     plan.grid()     # rejects plans whose targets enumerate duplicate pairs
-    ctl = Controller(reps=plan.reps, compile_once=plan.compile_once)
+    ctl = Controller(reps=plan.reps)
     qpolicy, qbudget = _plan_quality(plan)
     camp = Campaign(_plan_store(plan, store), ctl, workers=plan.workers,
                     quality=qpolicy, remeasure=qbudget)
@@ -629,7 +629,7 @@ def _classify(plan: SweepPlan, quality: str = "gate"):
     from repro.core.calibration import resolve_thresholds
 
     qpolicy, qbudget = _plan_quality(plan)
-    ctl = Controller(reps=plan.reps, compile_once=plan.compile_once)
+    ctl = Controller(reps=plan.reps)
     camp = Campaign(_plan_store(plan, plan.store), ctl, workers=plan.workers,
                     quality=qpolicy, remeasure=qbudget,
                     heal_quarantined=False)
@@ -1129,7 +1129,7 @@ def _explain_lines(plan: SweepPlan, *, covered: bool) -> list[str]:
     low, high, prov = resolve_thresholds(store)
     out.append(f"  thresholds: {prov} (low={low:g}, high={high:g})")
     qpolicy, qbudget = _plan_quality(plan)
-    ctl = Controller(reps=plan.reps, compile_once=plan.compile_once)
+    ctl = Controller(reps=plan.reps)
     camp = Campaign(store, ctl, workers=plan.workers, quality=qpolicy,
                     remeasure=qbudget, heal_quarantined=False,
                     thresholds=(low, high))

@@ -22,7 +22,7 @@ so every participant (the launcher, each worker subprocess, a human at the
 Plan JSON (one object, schema-versioned):
 
   {"sweep_plan": 1, "name": ..., "store": ..., "reps": 2, "shards": 2,
-   "workers": 1, "compile_once": true, "backend": "interpret",
+   "workers": 1, "backend": "interpret",
    "targets": [{"kind": "pallas", "modes": ["fp", "vmem"],
                 "params": {"kernel": "spmxv", "sizes": [256, 512],
                            "qs": [0.0, 1.0], "nnz_per_row": 16}},
@@ -229,7 +229,6 @@ class SweepPlan:
     reps: int = 2
     shards: int = 1
     workers: int = 1
-    compile_once: bool = True
     backend: str = "pallas"
     launcher: Optional[dict] = None
     retry: Optional[dict] = None
@@ -309,7 +308,7 @@ class SweepPlan:
         d = {"sweep_plan": PLAN_SCHEMA, "name": self.name,
              "store": self.store, "reps": self.reps,
              "shards": self.shards, "workers": self.workers,
-             "compile_once": self.compile_once, "backend": self.backend,
+             "backend": self.backend,
              "targets": [t.to_dict() for t in self.targets]}
         if self.launcher is not None:
             d["launcher"] = self.launcher
@@ -349,16 +348,21 @@ class SweepPlan:
     @classmethod
     def from_dict(cls, d: dict) -> "SweepPlan":
         """Rebuild (and validate) a plan from its JSON object; plans saved
-        before the launcher/retry fields existed load unchanged."""
+        before the launcher/retry fields existed load unchanged. Older plans
+        carry ``"compile_once": true``, which is read and ignored; false
+        asked for the trace-per-k sweep, which is gone, and is refused."""
         if d.get("sweep_plan") != PLAN_SCHEMA:
             raise PlanError(f"not a sweep plan (sweep_plan="
                             f"{d.get('sweep_plan')!r}, want {PLAN_SCHEMA})")
+        if not d.get("compile_once", True):
+            raise PlanError("compile_once: false asks for the trace-per-k "
+                            "sweep, which was removed; every sweep takes k "
+                            "at run time (drop the key)")
         plan = cls(name=d.get("name", ""), store=d.get("store", ""),
                    targets=[TargetSpec.from_dict(t)
                             for t in d.get("targets", [])],
                    reps=int(d.get("reps", 2)), shards=int(d.get("shards", 1)),
                    workers=int(d.get("workers", 1)),
-                   compile_once=bool(d.get("compile_once", True)),
                    backend=d.get("backend", "pallas"),
                    launcher=d.get("launcher"), retry=d.get("retry"),
                    store_format=d.get("store_format"),

@@ -38,10 +38,10 @@ are emitted by a bounded ``lax.fori_loop``:
     HLO holds ONE pattern in a loop body, so surviving ops cannot be counted
     on it, but the exact ``nacc`` oracle can be checked on its output — one
     executable per (kernel, mode) sweep, payload check included;
-  * the trace-per-k fallback (``Controller(compile_once=False)``) still
-    applies when a region cannot thread a traced k — e.g. a hand-rolled
+  * a region that cannot thread a traced k — e.g. a hand-rolled
     ``pallas_call`` without the scalar-prefetch operand, or a k that changes
-    buffer SHAPES rather than a loop trip count.
+    buffer SHAPES rather than a loop trip count — has no ``build_rt``, and
+    the Controller refuses to measure it: every sweep takes k at run time.
 """
 from __future__ import annotations
 

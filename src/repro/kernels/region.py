@@ -4,7 +4,7 @@ The loop-level (``loop_region``) and graph-level (``step_region``) injection
 sites have ridden the Controller/Campaign spine since PR 1; this adapter puts
 the instruction-granularity Pallas kernels on the same spine:
 
-  * ``build(mode, k)``    — one static-k executable (trace-per-k fallback);
+  * ``build(mode, k)``    — one static-k executable (k=0 reference, audit);
   * ``build_rt(mode)``    — ONE runtime-k executable per (kernel, mode): the
     noise quantity is a scalar-prefetch operand of the kernel (noise_slots
     runtime-k protocol), so ``Controller.run_mode`` sweeps a whole k-grid

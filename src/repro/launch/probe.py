@@ -11,12 +11,12 @@ one campaign tail (store naming, shard dispatch, reporting).
 Measured mode (default; reduced config, host backend) runs as a resumable
 CAMPAIGN: every (mode, k, t) point persists to a JSONL store under
 ``experiments/campaigns/`` and re-running skips everything already measured.
-The sweep itself uses the controller's compile-once path (one runtime-k
-executable per mode instead of one per sweep point):
+The sweep delivers k at run time (one runtime-k executable per mode instead
+of one per sweep point):
 
     PYTHONPATH=src python -m repro.launch.probe --arch gemma-2b --smoke \
         --kind train --modes fp_add32,vmem_ld,hbm_stream \
-        [--store PATH] [--fresh] [--workers N] [--no-compile-once]
+        [--store PATH] [--fresh] [--workers N]
 
 Serve mode probes the paged serving engine as TWO regions — the batched
 prefill and the decode tick — under one campaign, so the two phases of one
@@ -134,8 +134,8 @@ def build_step_region(arch: str, kind: str, modes: Sequence[str], *,
 
 
 def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
-               workers: int, compile_once: bool,
-               shard: Optional[tuple[int, int]], expect_no_measure: bool,
+               workers: int, shard: Optional[tuple[int, int]],
+               expect_no_measure: bool,
                header: str, audit: str = "gate",
                quality: str = "gate", backend: str = "pallas") -> None:
     """Build a one-target SweepPlan from CLI flags and execute it through
@@ -146,8 +146,7 @@ def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
 
     plan = SweepPlan(name=header, store=store or "", targets=[spec],
                      reps=reps, shards=(shard[1] if shard else 1),
-                     workers=workers, compile_once=compile_once,
-                     backend=backend)
+                     workers=workers, backend=backend)
     if not plan.store:
         first = plan.resolve()[0][1][0]
         plan.store = os.path.join(CAMPAIGN_DIR, f"{first.name}.jsonl")
@@ -160,7 +159,6 @@ def _run_adhoc(spec, *, reps: int, store: str | None, fresh: bool,
 def measured_probe(arch: str, kind: str, modes: list[str], *, seq: int,
                    batch: int, reps: int, store: str | None = None,
                    fresh: bool = False, workers: int = 1,
-                   compile_once: bool = True,
                    shard: Optional[tuple[int, int]] = None,
                    expect_no_measure: bool = False,
                    audit: str = "gate", quality: str = "gate") -> None:
@@ -179,8 +177,7 @@ def measured_probe(arch: str, kind: str, modes: list[str], *, seq: int,
                       {"arch": arch, "kind": kind, "seq": seq,
                        "batch": batch})
     _run_adhoc(spec, reps=reps, store=store, fresh=fresh, workers=workers,
-               compile_once=compile_once, shard=shard,
-               expect_no_measure=expect_no_measure, audit=audit,
+               shard=shard, expect_no_measure=expect_no_measure, audit=audit,
                quality=quality,
                header=f"measured probe: {arch} {kind} seq={seq} "
                       f"batch={batch}")
@@ -189,7 +186,6 @@ def measured_probe(arch: str, kind: str, modes: list[str], *, seq: int,
 def serve_probe(arch: str, modes: list[str], *, slots: int, prompt: int,
                 max_new: int, reps: int, store: str | None = None,
                 fresh: bool = False, workers: int = 1,
-                compile_once: bool = True,
                 shard: Optional[tuple[int, int]] = None,
                 expect_no_measure: bool = False,
                 audit: str = "gate", quality: str = "gate") -> None:
@@ -209,8 +205,7 @@ def serve_probe(arch: str, modes: list[str], *, slots: int, prompt: int,
                       {"arch": arch, "slots": slots, "prompt": prompt,
                        "max_new": max_new})
     _run_adhoc(spec, reps=reps, store=store, fresh=fresh, workers=workers,
-               compile_once=compile_once, shard=shard,
-               expect_no_measure=expect_no_measure, audit=audit,
+               shard=shard, expect_no_measure=expect_no_measure, audit=audit,
                quality=quality,
                header=f"serve probe: {arch} slots={slots} prompt={prompt}")
 
@@ -218,15 +213,14 @@ def serve_probe(arch: str, modes: list[str], *, slots: int, prompt: int,
 def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
                  n: Optional[int] = None, store: str | None = None,
                  fresh: bool = False, workers: int = 1,
-                 compile_once: bool = True,
                  shard: Optional[tuple[int, int]] = None,
                  expect_no_measure: bool = False,
                  audit: str = "gate", quality: str = "gate",
                  backend: str = "pallas") -> None:
     """Run the paper's methodology against a real Pallas kernel (on the
     TPU, or in the interpreter with ``backend="interpret"``). The sweep
-    rides the compile-once runtime-k path: one Pallas executable per
-    (kernel, mode), payload check included."""
+    delivers k at run time: one Pallas executable per (kernel, mode),
+    payload check included."""
     from repro.kernels.region import KERNEL_MODES, SIZE_DEFAULT, validate_size
 
     if kernel not in KERNEL_MODES:
@@ -249,8 +243,7 @@ def pallas_probe(kernel: str, modes: Optional[list[str]], *, reps: int,
                        "sizes": [n if n is not None else
                                  SIZE_DEFAULT[kernel]]})
     _run_adhoc(spec, reps=reps, store=store, fresh=fresh, workers=workers,
-               compile_once=compile_once, shard=shard,
-               expect_no_measure=expect_no_measure, audit=audit,
+               shard=shard, expect_no_measure=expect_no_measure, audit=audit,
                quality=quality, backend=backend,
                header=f"pallas probe: {kernel}")
 
@@ -416,8 +409,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--expect-no-measure", action="store_true",
                     help="exit non-zero if any fresh measurement was needed "
                          "(assert a merged/complete store replays fully)")
-    ap.add_argument("--no-compile-once", action="store_true",
-                    help="force the trace-per-k fallback sweep path")
     ap.add_argument("--audit", default="gate",
                     choices=("gate", "warn", "off"),
                     help="static noise-audit policy for whole-plan/ad-hoc "
@@ -454,7 +445,6 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
             ("--analytic", args.analytic), ("--modes", modes),
             ("--store", args.store), ("--reps", args.reps != 3),
             ("--workers", args.workers != 1),
-            ("--no-compile-once", args.no_compile_once),
             ("--kind", args.kind != "train"), ("--seq", args.seq != 128),
             ("--batch", args.batch != 4),
             ("--backend", args.backend != "pallas")) if given]
@@ -471,8 +461,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
             raise SystemExit("--pallas excludes --analytic and --serve")
         pallas_probe(args.pallas, modes, reps=args.reps, n=args.pallas_n,
                      store=args.store, fresh=args.fresh,
-                     workers=args.workers,
-                     compile_once=not args.no_compile_once, shard=shard,
+                     workers=args.workers, shard=shard,
                      expect_no_measure=args.expect_no_measure,
                      audit=args.audit, quality=args.quality,
                      backend=args.backend)
@@ -486,8 +475,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         serve_probe(args.arch, modes or list(DEFAULT_GRAPH_MODES),
                     slots=args.batch, prompt=args.seq, max_new=args.max_new,
                     reps=args.reps, store=args.store, fresh=args.fresh,
-                    workers=args.workers,
-                    compile_once=not args.no_compile_once, shard=shard,
+                    workers=args.workers, shard=shard,
                     expect_no_measure=args.expect_no_measure,
                     audit=args.audit, quality=args.quality)
         return
@@ -504,9 +492,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
                        modes or list(DEFAULT_GRAPH_MODES),
                        seq=args.seq, batch=args.batch, reps=args.reps,
                        store=args.store, fresh=args.fresh,
-                       workers=args.workers,
-                       compile_once=not args.no_compile_once,
-                       shard=shard,
+                       workers=args.workers, shard=shard,
                        expect_no_measure=args.expect_no_measure,
                        audit=args.audit, quality=args.quality)
 

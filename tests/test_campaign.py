@@ -74,38 +74,29 @@ def test_sweep_compiles_at_most_two_executables():
     2 executables (the runtime-k one + the static payload check) instead of
     one per k."""
     region, traces = _make_counting_region()
-    ctl = Controller(reps=2, compile_once=True)
+    ctl = Controller(reps=2)
     res = ctl.run_mode(region, "fp_add32", ks=DEFAULT_KS)
     assert traces["n"] <= 2, f"{traces['n']} executables for one sweep"
     assert len(res.curve.ks) >= 3    # the sweep actually happened
     assert res.injection is not None  # payload was verified (static trace)
 
 
-def test_fallback_compiles_per_k():
-    region, traces = _make_counting_region()
-    # stop_ratio high: a wall-clock spike on a loaded container must not
-    # trigger the online stop and truncate the sweep under test
-    ctl = Controller(reps=2, compile_once=False, verify_payload=False,
-                     stop_ratio=100.0)
-    ctl.run_mode(region, "fp_add32", ks=(0, 2, 4, 8))
-    assert traces["n"] >= 4          # the paper's cost model: one per k
+@pytest.mark.parametrize("call", ["probe_sensitivity", "run_mode"])
+def test_controller_refuses_region_without_runtime_k_build(call):
+    """Runtime k is the only way a sweep delivers k: a target without
+    ``build_rt`` is refused by name, and nothing is built or measured."""
+    from repro.core.controller import RegionTarget
 
+    def never(*a):
+        raise AssertionError("a refused region must not be built")
 
-def test_compile_once_and_fallback_same_classification():
-    """A/B check: both sweep paths characterize a small region identically
-    (same surviving-payload verdicts; classification from real timings may
-    wobble, absorption fit fields must exist on both)."""
-    region, _ = _make_counting_region("ab_region")
-    ks = (0, 2, 4, 8, 16)
-    # stop_ratio high: load spikes must not early-stop either sweep (the
-    # ks[:3] assertion below relies on all three points being measured)
-    fast = Controller(reps=2, compile_once=True, stop_ratio=100.0)
-    slow = Controller(reps=2, compile_once=False, stop_ratio=100.0)
-    r_fast = fast.run_mode(region, "fp_add32", ks=ks)
-    r_slow = slow.run_mode(region, "fp_add32", ks=ks)
-    assert r_fast.curve.ks[:3] == r_slow.curve.ks[:3] == [0, 2, 4]
-    assert r_fast.injection.payload == r_slow.injection.payload
-    assert r_fast.fit.t0 > 0 and r_slow.fit.t0 > 0
+    target = RegionTarget(name="replay_only", build=never, args_for=never)
+    ctl = Controller(reps=2)
+    with pytest.raises(ValueError, match="'replay_only' has no runtime-k "
+                                         "build; it can be replayed, not "
+                                         "measured"):
+        getattr(ctl, call)(target, "fp_add32")
+    assert ctl._rt_cache == {}
 
 
 def test_loop_region_build_rt_matches_static():
@@ -181,8 +172,8 @@ def test_campaign_partial_store_resumes_missing_points(tmp_path):
 
 
 def test_campaign_settings_mismatch_discards_store(tmp_path):
-    """A store measured under different settings (reps / sweep path) must not
-    be spliced into a new curve: the pair is discarded and remeasured."""
+    """A store measured under different settings (reps) must not be
+    spliced into a new curve: the pair is discarded and remeasured."""
     store = str(tmp_path / "s.jsonl")
     region1, _ = _make_counting_region("meta_region")
     c1 = Campaign(store, Controller(reps=2, verify_payload=False))
@@ -199,6 +190,37 @@ def test_campaign_settings_mismatch_discards_store(tmp_path):
     c3 = Campaign(store, Controller(reps=3, verify_payload=False))
     c3.sweep_mode(region3, "fp_add32")
     assert c3.stats.measured == 0
+
+
+def test_campaign_replays_pairs_stored_with_compile_once_false(tmp_path):
+    """Stores written by the removed trace-per-k sweep carry
+    ``"compile_once": false`` in their meta records; under the same reps
+    they replay without a measurement, and the store keeps its bytes."""
+    store = str(tmp_path / "s.jsonl")
+    region, traces = _make_counting_region("old_store")
+    c1 = Campaign(store, Controller(reps=2, verify_payload=False,
+                                    stop_ratio=100.0))
+    first = c1.sweep_mode(region, "fp_add32")
+    c1.store.close()
+    with open(store) as f:
+        recs = [json.loads(line) for line in f]
+    metas = [r for r in recs if r["kind"] == "meta"]
+    assert [m["compile_once"] for m in metas] == [True]
+    for r in metas:
+        r["compile_once"] = False
+    with open(store, "w") as f:
+        f.writelines(json.dumps(r) + "\n" for r in recs)
+    before = open(store, "rb").read()
+
+    traces["n"] = 0
+    c2 = Campaign(store, Controller(reps=2, verify_payload=False))
+    again = c2.sweep_mode(region, "fp_add32")
+    c2.store.close()
+    assert c2.stats.measured == 0 and c2.stats.cached == len(first.curve.ks)
+    assert again.curve.ks == first.curve.ks
+    assert again.curve.ts == first.curve.ts
+    assert traces["n"] == 0                      # nothing was built
+    assert open(store, "rb").read() == before    # no meta rewritten
 
 
 def test_campaign_worker_pool(tmp_path):

@@ -123,6 +123,47 @@ def test_plan_not_a_plan_file(tmp_path):
         SweepPlan.load(path)
 
 
+def test_plan_refuses_compile_once_false(tmp_path):
+    """Plans saved before the trace-per-k sweep went carry
+    ``"compile_once": true``: it loads (as does a plan without the key)
+    and is not written back. False asked for the removed sweep: refused."""
+    plan, path = _plan(tmp_path)
+    d = plan.to_dict()
+    assert "compile_once" not in d
+    assert SweepPlan.from_dict({**d, "compile_once": True}).to_dict() == d
+    assert SweepPlan.from_dict(d).digest() == plan.digest()
+    with pytest.raises(PlanError, match="trace-per-k sweep, which was "
+                                        "removed"):
+        SweepPlan.from_dict({**d, "compile_once": False})
+
+
+def _option_strings(parser):
+    import argparse
+
+    for action in parser._actions:
+        if isinstance(action, argparse._SubParsersAction):
+            for sub in action.choices.values():
+                yield from _option_strings(sub)
+        else:
+            yield from action.option_strings
+
+
+@pytest.mark.parametrize("cli,argv", [
+    ("repro.fleet.cli", ["plan", "--out", "p.json", "--pallas", "probe"]),
+    ("repro.launch.probe", ["--pallas", "probe"]),
+], ids=["fleet", "launch.probe"])
+def test_cli_has_no_compile_once_flag(cli, argv, capsys):
+    """Neither CLI offers a switch back to trace-per-k sweeps."""
+    import importlib
+
+    parser = importlib.import_module(cli).build_parser()
+    assert not [o for o in _option_strings(parser) if "compile" in o]
+    parser.parse_args(argv)
+    with pytest.raises(SystemExit):
+        parser.parse_args([*argv, "--no-compile-once"])
+    assert "--no-compile-once" in capsys.readouterr().err
+
+
 # ---------------------------------------------------------------------------
 # completeness queries (the per-(region, mode) grid query the executor needs)
 # ---------------------------------------------------------------------------

@@ -59,7 +59,9 @@ def _region(payload_check):
 
     x = jnp.ones((8, 128), jnp.float32)
     return RegionTarget(name="r", build=build, args_for=lambda m, k: (x,),
-                        body_size=1, payload_check=payload_check)
+                        body_size=1, payload_check=payload_check,
+                        build_rt=lambda m: jax.jit(lambda k, x: x * 2.0 + k),
+                        args_for_rt=lambda m: (x,))
 
 
 def _report(payload, expected=4, **kw):

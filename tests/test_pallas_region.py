@@ -41,7 +41,7 @@ def test_pallas_sweep_compiles_at_most_two_per_mode(kernel):
     mode) — the runtime-k build the sweep times also counts the patterns —
     inside the ≤2-executables guarantee of the loop/graph layers."""
     region, traces = _counting_region(kernel, **SIZES[kernel])
-    ctl = Controller(reps=2, compile_once=True)
+    ctl = Controller(reps=2)
     before = 0
     for mode in KERNEL_MODES[kernel]:
         ctl.probe_sensitivity(region, mode)
@@ -66,42 +66,6 @@ def test_pallas_payload_check_after_the_sweep_traces_nothing(kernel):
     for k in (4, CHECK_K_MAX + 4):
         assert region.payload_check(mode, k).ok()
     assert traces["n"] == 1
-
-
-def test_pallas_fallback_payload_check_builds_one_runtime_k_executable():
-    """On the trace-per-k path the check builds the runtime-k executable
-    once, in place of a static build: one more than the sweep alone."""
-    built = {}
-    for verify in (False, True):
-        region, traces = _counting_region("probe", n_steps=8)
-        ctl = Controller(reps=2, compile_once=False, stop_ratio=100.0,
-                         verify_payload=verify)
-        res = ctl.run_mode(region, "fp", ks=(0, 2, 4))
-        built[verify] = traces["n"]
-    assert res.injection.payload == res.injection.expected == 4
-    assert built[True] == built[False] + 1
-
-
-def test_pallas_fallback_compiles_per_k():
-    region, traces = _counting_region("probe", n_steps=8)
-    ctl = Controller(reps=2, compile_once=False, verify_payload=False,
-                     stop_ratio=100.0)
-    ctl.run_mode(region, "fp", ks=(0, 2, 4, 8))
-    assert traces["n"] >= 4          # the paper's cost model: one per k
-
-
-def test_pallas_static_and_runtime_sweeps_agree():
-    """A/B: both sweep paths measure the same program (payload verdicts
-    identical; fit fields exist on both)."""
-    region, _ = _counting_region("spmxv", n=256)
-    ks = (0, 2, 4, 8)
-    fast = Controller(reps=2, compile_once=True, stop_ratio=100.0)
-    slow = Controller(reps=2, compile_once=False, stop_ratio=100.0)
-    r_fast = fast.run_mode(region, "fp", ks=ks)
-    r_slow = slow.run_mode(region, "fp", ks=ks)
-    assert r_fast.curve.ks[:3] == r_slow.curve.ks[:3] == [0, 2, 4]
-    assert r_fast.injection.payload == r_slow.injection.payload
-    assert r_fast.fit.t0 > 0 and r_slow.fit.t0 > 0
 
 
 def test_pallas_payload_check_oracle():

@@ -40,7 +40,7 @@ def test_probe_end_to_end_measured():
     sweeps, payload verification, classification."""
     from repro.configs import get_smoke_config
     from repro.configs.base import ShapeConfig
-    from repro.core import probe_step
+    from repro.core import Controller, step_region
     from repro.core.noise import NoiseScale, make_modes
     from repro.models.model import build
 
@@ -50,11 +50,34 @@ def test_probe_end_to_end_measured():
     batch = api.dummy_batch(ShapeConfig("p", "train", 64, 2))
 
     modes = make_modes(NoiseScale(mxu_dim=32, hbm_mib=4, chase_len=1 << 16))
-    pr = probe_step(lambda p, b: api.loss(p, b)[0], (params, batch),
-                    modes["fp_add32"], ks=(0, 2, 4, 8), reps=2)
-    assert pr.injection.payload >= 4
-    assert pr.fit.t0 > 0
-    assert len(pr.curve.ks) >= 3
+    region = step_region("gemma_2b_train", lambda p, b: api.loss(p, b)[0],
+                         (params, batch), {"fp_add32": modes["fp_add32"]})
+    res = Controller(reps=2).run_mode(region, "fp_add32", ks=(0, 2, 4, 8))
+    assert res.injection.payload >= 4
+    assert res.fit.t0 > 0
+    assert len(res.curve.ks) >= 3
+
+
+@pytest.mark.parametrize("ks", [(0, 2, 6), (0, 8, 24)],
+                         ids=["below-cap", "above-cap"])
+def test_step_region_payload_check_uses_controller_rule(ks):
+    """A step probed through the Controller takes the fleet's payload rule:
+    the census runs at the last nonzero k swept, capped at CHECK_K_MAX, and
+    the outputs stay bit-identical to k=0."""
+    from repro.core import Controller, step_region
+    from repro.core.noise import NoiseScale, make_modes
+    from repro.kernels.region import CHECK_K_MAX
+
+    modes = make_modes(NoiseScale(mxu_dim=32, hbm_mib=4, chase_len=1 << 16))
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 64))
+    region = step_region("tiny_step", lambda a: jnp.tanh(a @ a.T), (x,),
+                         {"fp_add32": modes["fp_add32"]})
+    res = Controller(reps=2, stop_ratio=100.0).run_mode(region, "fp_add32",
+                                                         ks=ks)
+    assert res.curve.ks == list(ks)
+    want = min(ks[-1], CHECK_K_MAX)
+    assert res.injection.expected == res.injection.payload == want
+    assert res.injection.ref_err == 0.0 and res.injection.ok()
 
 
 def test_analytic_probe_from_record(tmp_path):
