@@ -5,12 +5,15 @@ sites have ridden the Controller/Campaign spine since PR 1; this adapter puts
 the instruction-granularity Pallas kernels on the same spine:
 
   * ``build(mode, k)``    — one static-k executable (k=0 reference, audit);
-  * ``build_rt(mode)``    — ONE runtime-k executable per (kernel, mode): the
-    noise quantity is a scalar-prefetch operand of the kernel (noise_slots
+  * ``build_rt(mode)``    — ONE runtime-k executable per (kernel, mode,
+    static block values, argument shapes) in the process: the noise
+    quantity is a scalar-prefetch operand of the kernel (noise_slots
     runtime-k protocol), so ``Controller.run_mode`` sweeps a whole k-grid
     AND checks its payload on that one executable instead of one per k —
     the paper's "Fast: ✗" concession, escaped at the last layer that still
-    paid it;
+    paid it. The jitted callable takes every buffer as an argument, so
+    same-shaped regions (a family's q values, the regions of a later
+    campaign) share it, and only a new shape traces, lowers and loads;
   * campaigns persist/replay (region, mode, k, t) records for Pallas regions
     exactly like any other RegionTarget — a completed Pallas campaign
     replays with zero new measurements;
@@ -139,12 +142,25 @@ class _KernelSpec:
     name: str
     args: tuple
     static_fn: Callable[[str, int], Callable]   # (mode, k) -> fn(*args)
-    rt_fn: Callable[[str], Callable]            # mode -> fn(k, *args)
+    # module-level fn(k, *args, mode=, **rt_static), and the static values
+    # it takes besides the mode, as sorted (keyword, value) pairs
+    rt_kernel: Callable
+    rt_static: tuple
     oracle: Callable[[str, int], Optional[jnp.ndarray]]
     n_steps: int                                # grid steps visiting the slot
     body_size: int                              # |l1.l2| stand-in for Abs^rel
     # () -> float32 reference of the main output; None: no main output
     reference: Optional[Callable[[], np.ndarray]] = None
+
+    def rt_fn(self, mode: str) -> Callable:
+        """fn(k, *args): the runtime-k kernel with its static values bound,
+        closing over no buffer."""
+        return functools.partial(self.rt_kernel, mode=mode,
+                                 **dict(self.rt_static))
+
+
+def _statics(**values) -> tuple:
+    return tuple(sorted(values.items()))
 
 
 def _matmul_spec(interpret: bool, *, n: int = 256, bm: int = 128,
@@ -160,11 +176,6 @@ def _matmul_spec(interpret: bool, *, n: int = 256, bm: int = 128,
             a, b, noise, mode=mode, k_noise=k, bm=bm, bn=bn, bk=bk,
             interpret=interpret)
 
-    def rt_fn(mode):
-        return lambda k, a, b, noise: matmul_pallas_rt(
-            k, a, b, noise, mode=mode, bm=bm, bn=bn, bk=bk,
-            interpret=interpret)
-
     def oracle(mode, k):
         if mode == "fp":
             return ns.expected_fp_noise(noise, k, grid_steps)
@@ -174,7 +185,9 @@ def _matmul_spec(interpret: bool, *, n: int = 256, bm: int = 128,
         return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
 
     return _KernelSpec(_matmul_name(n=n), (a, b, noise), static_fn,
-                       rt_fn, oracle, grid_steps, body_size=3,
+                       matmul_pallas_rt,
+                       _statics(bm=bm, bn=bn, bk=bk, interpret=interpret),
+                       oracle, grid_steps, body_size=3,
                        reference=reference)
 
 
@@ -190,10 +203,6 @@ def _spmxv_spec(interpret: bool, *, n: int = 512, nnz_per_row: int = 16,
         return lambda vals, cols, x: spmv_ell_pallas(
             vals, cols, x, br=br, mode=mode, k_noise=k, interpret=interpret)
 
-    def rt_fn(mode):
-        return lambda k, vals, cols, x: spmv_ell_pallas_rt(
-            k, vals, cols, x, br=br, mode=mode, interpret=interpret)
-
     def oracle(mode, k):
         if mode == "fp":
             return fp_noise_ell_ref(vals, k, br)
@@ -206,7 +215,8 @@ def _spmxv_spec(interpret: bool, *, n: int = 512, nnz_per_row: int = 16,
         return (v.astype(np.float64) * xx.astype(np.float64)[c]).sum(axis=1)
 
     return _KernelSpec(_spmxv_name(n=n, nnz_per_row=nnz_per_row, q=q),
-                       (vals, cols, x), static_fn, rt_fn, oracle, nb,
+                       (vals, cols, x), static_fn, spmv_ell_pallas_rt,
+                       _statics(br=br, interpret=interpret), oracle, nb,
                        body_size=4, reference=reference)
 
 
@@ -233,11 +243,6 @@ def _attention_spec(interpret: bool, *, batch: int = 1, heads: int = 2,
             q, k, v, noise, causal=causal, bq=bq, bk=bk, mode=mode,
             k_noise=kn, interpret=interpret)
 
-    def rt_fn(mode):
-        return lambda kn, q, k, v, noise: flash_attention_pallas_rt(
-            kn, q, k, v, noise, causal=causal, bq=bq, bk=bk, mode=mode,
-            interpret=interpret)
-
     def oracle(mode, kn):
         if mode == "fp":
             return ns.expected_fp_noise(noise, kn, grid_steps)
@@ -250,8 +255,10 @@ def _attention_spec(interpret: bool, *, batch: int = 1, heads: int = 2,
 
     return _KernelSpec(_attention_name(batch=batch, heads=heads, seq=seq,
                                        head_dim=head_dim),
-                       (q, k, v, noise), static_fn, rt_fn, oracle,
-                       grid_steps, body_size=12, reference=reference)
+                       (q, k, v, noise), static_fn, flash_attention_pallas_rt,
+                       _statics(causal=causal, bq=bq, bk=bk,
+                                interpret=interpret),
+                       oracle, grid_steps, body_size=12, reference=reference)
 
 
 def _probe_spec(interpret: bool, *, n_steps: int = 64) -> _KernelSpec:
@@ -262,15 +269,13 @@ def _probe_spec(interpret: bool, *, n_steps: int = 64) -> _KernelSpec:
             noise, mode=mode, k_noise=k, n_steps=n_steps,
             interpret=interpret)
 
-    def rt_fn(mode):
-        return lambda k, noise: probe_pallas_rt(
-            k, noise, mode=mode, n_steps=n_steps, interpret=interpret)
-
     def oracle(mode, k):
         return probe_ref(noise, mode=mode, k_noise=k, n_steps=n_steps)
 
     return _KernelSpec(_probe_name(n_steps=n_steps), (noise,), static_fn,
-                       rt_fn, oracle, n_steps, body_size=1)
+                       probe_pallas_rt,
+                       _statics(n_steps=n_steps, interpret=interpret),
+                       oracle, n_steps, body_size=1)
 
 
 _SPECS = {
@@ -285,6 +290,41 @@ def _nacc_of(result):
     return result[-1] if isinstance(result, (tuple, list)) else result
 
 
+def _jit(fn, trace_hook):
+    if trace_hook is None:
+        return jax.jit(fn)
+
+    def counted(*args):
+        trace_hook()
+        return fn(*args)
+
+    return jax.jit(counted)
+
+
+# the runtime-k callables of every Pallas region in the process: one jitted
+# callable per (kernel function, mode, static values, trace hook), to which
+# jax's own cache adds the argument shapes and dtypes. It closes over no
+# buffer, so regions whose programs are identical (a family's q values, the
+# regions each later campaign resolves) run one executable, and only a new
+# shape traces, lowers and loads. Entries live as long as the process.
+_RT_SHARED: dict[tuple, Callable] = {}
+
+
+def _shared_rt(region: str, kernel: str, spec: _KernelSpec, mode: str,
+               trace_hook: Optional[Callable[[], None]]) -> Callable:
+    """The shared runtime-k callable for ``region``'s ``mode``, resolved
+    inside the regions layer's span; its ``reused`` stat says whether the
+    callable already existed in the process."""
+    key = (spec.rt_kernel, mode, spec.rt_static, trace_hook)
+    fn = _RT_SHARED.get(key)
+    with span("campaign.region", region=region, kernel=kernel, mode=mode,
+              reused=fn is not None):
+        if fn is None:      # setdefault: sweeps on a thread pool race here
+            fn = _RT_SHARED.setdefault(key, _jit(spec.rt_fn(mode),
+                                                 trace_hook))
+    return fn
+
+
 def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
                   trace_hook: Optional[Callable[[], None]] = None,
                   **sizes) -> RegionTarget:
@@ -294,7 +334,9 @@ def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
     ``trace_hook`` (tests): called once per Python trace of any executable
     this region builds — each jit compilation traces exactly once, so the
     hook counts compiled executables (one per (kernel, mode) sweep, payload
-    check included).
+    check included). The runtime-k callable is shared with every region of
+    the same kernel, mode, static values and hook, as it is between
+    production regions.
     ``sizes``: forwarded to the kernel's spec builder (e.g. ``n=``, ``q=``).
     """
     if kernel not in _SPECS:
@@ -305,16 +347,6 @@ def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
         spec = _SPECS[kernel](interpret, **sizes)
     modes = KERNEL_MODES[kernel]
 
-    def _jit(fn):
-        if trace_hook is None:
-            return jax.jit(fn)
-
-        def counted(*args):
-            trace_hook()
-            return fn(*args)
-
-        return jax.jit(counted)
-
     def _check_mode(mode):
         if mode not in modes:
             raise ValueError(f"kernel {kernel!r} supports noise modes "
@@ -322,17 +354,17 @@ def pallas_region(kernel: str, *, backend: str = "pallas", name: str = "",
 
     def build(mode: str, k: int):
         if not mode or k == 0:
-            return _jit(spec.static_fn("none", 0))
+            return _jit(spec.static_fn("none", 0), trace_hook)
         _check_mode(mode)
-        return _jit(spec.static_fn(mode, k))
+        return _jit(spec.static_fn(mode, k), trace_hook)
 
     def args_for(mode: str, k: int):
         return spec.args
 
-    @functools.cache     # the payload check reuses the sweep's executable
+    @functools.cache     # one span per (region, mode), not per call
     def build_rt(mode: str):
         _check_mode(mode)
-        return _jit(spec.rt_fn(mode))
+        return _shared_rt(name or spec.name, kernel, spec, mode, trace_hook)
 
     def args_for_rt(mode: str):
         return spec.args

@@ -1,7 +1,9 @@
 """The Pallas layer on the Controller/Campaign spine: compile-count
 guarantees (one executable per (kernel, mode) sweep, payload check
-included), oracle payload verification, campaign persist/replay with zero
-new measurements, and multi-size families sharing one store namespace."""
+included, shared by same-shaped regions), oracle payload verification,
+campaign persist/replay with zero new measurements, and multi-size families
+sharing one store namespace."""
+import contextlib
 import dataclasses
 
 import jax.numpy as jnp
@@ -107,12 +109,12 @@ def _one_pattern_short(monkeypatch, kernel):
 
     def short_spec(interpret, **sizes):
         spec = make_spec(interpret, **sizes)
+        rt_kernel = spec.rt_kernel
 
-        def rt_fn(mode):
-            fn = spec.rt_fn(mode)
-            return lambda k, *args: fn(jnp.maximum(k - 1, 0), *args)
+        def short(k, *args, **static):
+            return rt_kernel(jnp.maximum(k - 1, 0), *args, **static)
 
-        return dataclasses.replace(spec, rt_fn=rt_fn)
+        return dataclasses.replace(spec, rt_kernel=short)
 
     monkeypatch.setitem(region_mod._SPECS, kernel, short_spec)
 
@@ -238,3 +240,109 @@ def test_pallas_rt_callable_is_memoized_on_controller():
     assert fn is ctl._rt_fn(region, "fp")
     fn(jnp.int32(2), *region.args_for_rt("fp"))
     assert traces["n"] == 1
+
+
+def _counting_family(kernel, sizes, **kw):
+    traces = {"n": 0}
+    fam = pallas_family(
+        kernel, sizes, backend="interpret",
+        trace_hook=lambda: traces.__setitem__("n", traces["n"] + 1), **kw)
+    return fam, traces
+
+
+def test_same_shaped_family_traces_once_per_mode():
+    """The fig7 plane's q values have one shape: across both members'
+    sweeps and payload checks each mode traces exactly once."""
+    fam, traces = _counting_family("spmxv", [256], qs=[0.0, 1.0])
+    ctl = Controller(reps=2, stop_ratio=100.0)
+    for region in fam:
+        for mode in KERNEL_MODES["spmxv"]:
+            res = ctl.run_mode(region, mode, ks=(0, 2, 4))
+            assert res.injection.ok()
+            assert region.payload_check(mode, CHECK_K_MAX + 4).ok()
+    assert traces["n"] == len(KERNEL_MODES["spmxv"])
+
+
+@pytest.mark.parametrize("other", [{"n": 512}, {"n": 256, "br": 64}],
+                         ids=["rows", "block"])
+def test_another_shape_or_block_traces_its_own(other):
+    """A member of another size (jax adds the shapes to the key), or of
+    another block (a static value of the callable), traces its own."""
+    traces = {"n": 0}
+
+    def hook():
+        traces["n"] += 1
+
+    base = pallas_region("spmxv", backend="interpret", n=256,
+                         trace_hook=hook)
+    twin = pallas_region("spmxv", backend="interpret", n=256, q=1.0,
+                         trace_hook=hook)
+    odd = pallas_region("spmxv", backend="interpret", trace_hook=hook,
+                        **other)
+    for region, want in ((base, 1), (twin, 1), (odd, 2)):
+        assert region.payload_check("fp", 4).ok()
+        assert traces["n"] == want
+    assert twin.build_rt("fp") is base.build_rt("fp")
+
+
+def _float64_spmv(vals, cols, x):
+    v, c, xx = (np.asarray(t, np.float64) for t in (vals, cols, x))
+    return (v * xx[c.astype(np.int64)]).sum(axis=1)
+
+
+@pytest.mark.parametrize("mode", sorted(KERNEL_MODES["spmxv"]))
+def test_shared_runtime_k_callable_closes_over_no_buffer(mode):
+    """After q=0 has built the shared callable, q=1 runs it on its own
+    buffers: its payload check passes, and its main output is its own
+    float64 product, not q=0's."""
+    q0, q1 = pallas_family("spmxv", [256], qs=[0.0, 1.0],
+                           backend="interpret")
+    assert q0.payload_check(mode, 4).ok()
+    assert q1.build_rt(mode) is q0.build_rt(mode)
+    assert q1.payload_check(mode, 4).ok()
+
+    outs = {}
+    for q, region in ((0, q0), (1, q1)):
+        args = region.args_for_rt(mode)
+        y = np.asarray(region.build_rt(mode)(jnp.int32(4), *args)[0],
+                       np.float64).reshape(-1)
+        outs[q] = (y, _float64_spmv(*args))
+    y1, ref1 = outs[1]
+    y0, ref0 = outs[0]
+    scale = np.max(np.abs(ref1))
+    assert np.max(np.abs(y1 - ref1)) / scale < region_mod.REF_TOL["spmxv"]
+    assert np.max(np.abs(y1 - y0)) / scale > 1e-2
+    assert np.max(np.abs(y0 - ref0)) / np.max(np.abs(ref0)) \
+        < region_mod.REF_TOL["spmxv"]
+
+
+def test_runtime_k_resolution_reports_reuse(monkeypatch):
+    """Each region's runtime-k resolution opens the regions layer's span
+    with ``reused`` false where it built the shared callable, true where it
+    found it; the static block values and the mode are part of the key."""
+    opened = []
+
+    @contextlib.contextmanager
+    def stub(name, **meta):
+        opened.append((name, meta))
+        yield
+
+    monkeypatch.setattr(region_mod, "span", stub)
+
+    def hook():
+        pass
+
+    fam = pallas_family("spmxv", [256], qs=[0.0, 1.0], backend="interpret",
+                        trace_hook=hook)
+    for region in fam:
+        for mode in KERNEL_MODES["spmxv"]:
+            region.build_rt(mode)
+            region.build_rt(mode)        # once per (region, mode)
+    resolved = [(m["region"], m["kernel"], m["mode"], m["reused"])
+                for name, m in opened
+                if name == "campaign.region" and "mode" in m]
+    q0, q1 = (r.name for r in fam)
+    assert resolved == [(q0, "spmxv", "fp", False),
+                        (q0, "spmxv", "vmem", False),
+                        (q1, "spmxv", "fp", True),
+                        (q1, "spmxv", "vmem", True)]
